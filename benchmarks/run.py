@@ -52,6 +52,9 @@ def main(argv=None):
     ap.add_argument("--no-rollup", action="store_true",
                     help="skip writing the repo-root BENCH_pipeline.json")
     args = ap.parse_args(argv)
+    from repro.kernels import compat
+
+    compat.enable_compile_cache()
     known = [name for name, _ in SUITES]
     only = args.only.split(",") if args.only else None
     if only:

@@ -411,7 +411,10 @@ def sharded_fused_resident_bytes(n_local: int, Pn: int, B: int, wave: int, L: in
 def sharded_fused_eligible(n_local: int, Pn: int, B: int, wave: int, L: int) -> bool:
     """The bitset_wave eligibility gate composed with shard-local shapes: the
     fused route only runs where its resident state fits the same budget the
-    kernel enforces (`ops.BITSET_WAVE_VMEM_BUDGET`)."""
+    kernel enforces (`ops.BITSET_WAVE_VMEM_BUDGET`). The sharded fused wave
+    is a jnp program, not the VMEM kernel, so its state is counted as
+    dense words here; the kernel's own count, with Mosaic's lane padding and
+    double buffers, is `ops.bitset_wave_vmem_bytes`."""
     from repro.kernels import ops as kops
 
     return sharded_fused_resident_bytes(n_local, Pn, B, wave, L) <= kops.BITSET_WAVE_VMEM_BUDGET

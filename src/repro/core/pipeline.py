@@ -38,6 +38,7 @@ Flags expose the paper's ablations:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -345,14 +346,17 @@ class _Driver:
 
     # -- driver loop --------------------------------------------------------
     def run(self):
-        if self.inj is None:
-            return self._loop()
         from repro.kernels import registry
 
-        # every registry.dispatch anywhere in the run reports to the
-        # injector (the "dispatch" site / per-kernel fault seam)
-        with registry.dispatch_hook(self.inj.on_dispatch):
-            return self._loop()
+        with contextlib.ExitStack() as stack:
+            if self.inj is not None:
+                # every registry.dispatch anywhere in the run reports to the
+                # injector (the "dispatch" site / per-kernel fault seam)
+                stack.enter_context(registry.dispatch_hook(self.inj.on_dispatch))
+            counts = stack.enter_context(registry.count_dispatches())
+            self._loop()
+        # which kernels ran in which mode (pallas / interpret / ref)
+        self.stats["kernel_dispatches"] = registry.dispatch_report(counts)
 
     def _loop(self):
         while True:

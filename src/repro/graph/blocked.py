@@ -22,6 +22,8 @@ active vector with one segment_sum (bits are disjoint, so sum == OR).
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Tuple
 
 import numpy as np
 import jax
@@ -54,10 +56,28 @@ class BlockedStructure:
     def words_per_block(self) -> int:
         return self.bn * self.bnw
 
+    @functools.cached_property
+    def device_arrays(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """(pairs, edge_word, edge_bit) as int32 device arrays, uploaded on
+        first use and kept: every sweep and hop needs them, and at R-MAT
+        scale 20 they are 0.6 GB of host arrays. The first use may come
+        inside a traced program, so they are made eagerly (never a tracer
+        kept past its trace)."""
+        if self.nnzb * self.words_per_block >= 2**31:
+            raise NotImplementedError(
+                f"{self.nnzb} blocks of {self.words_per_block} words overflow "
+                "the int32 word index; use a smaller bn")
+        with jax.ensure_compile_time_eval():
+            return (jnp.asarray(self.pairs, jnp.int32),
+                    jnp.asarray(self.edge_word, jnp.int32),
+                    jnp.asarray(self.edge_bit.view(np.int32)))
+
 
 def build_blocked_structure(src: np.ndarray, dst: np.ndarray, n: int, bn: int = 256) -> BlockedStructure:
-    """Build from dst-sorted arcs. bn must be a multiple of 32 (one lane word)."""
-    assert bn % 32 == 0
+    """Build from dst-sorted arcs. bn must be a power of two >= 32 (a whole
+    number of lane words per block row)."""
+    if bn < 32 or bn & (bn - 1):
+        raise ValueError(f"block size bn={bn} must be a power of two >= 32")
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     n_blocks_v = max((n + bn - 1) // bn, 1)
@@ -86,15 +106,18 @@ def build_blocked_structure(src: np.ndarray, dst: np.ndarray, n: int, bn: int = 
 
 
 def masks_from_active(bs: BlockedStructure, edge_active: jnp.ndarray) -> jnp.ndarray:
-    """Dynamic block bitmasks uint32[nnzb, bn, bnw] from the per-arc active
-    vector (dst-sorted order). Bits are disjoint per word, so segment-sum of
-    the selected bit values equals the bitwise OR."""
-    total_words = bs.nnzb * bs.words_per_block
-    bits = jnp.where(edge_active, jnp.asarray(bs.edge_bit), jnp.uint32(0))
+    """Dynamic block bitmasks int32[nnzb, 1, bn * bnw] from the per-arc
+    active vector (dst-sorted order): block b's word `row * bnw + col // 32`
+    holds bit `col % 32`. One row of words per block keeps the TPU layout
+    dense (a trailing [bn, bnw] pair would pad bnw to 128 lanes), and int32
+    is the kernels' own word type, so no converted copy is made. Bits are
+    disjoint per word, so segment-sum of the selected bit values equals the
+    bitwise OR (bit 31 wraps to the sign bit, as it should)."""
+    _, edge_word, edge_bit = bs.device_arrays
+    bits = jnp.where(edge_active, edge_bit, jnp.int32(0))
     flat = jax.ops.segment_sum(
-        bits, jnp.asarray(bs.edge_word, dtype=jnp.int32), num_segments=total_words
-    )
-    return flat.reshape(bs.nnzb, bs.bn, bs.bnw)
+        bits, edge_word, num_segments=bs.nnzb * bs.words_per_block)
+    return flat.reshape(bs.nnzb, 1, bs.words_per_block)
 
 
 def pad_values(vals: jnp.ndarray, bs: BlockedStructure) -> jnp.ndarray:

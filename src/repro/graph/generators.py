@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.graph.structs import Graph
+from repro.graph.structs import Graph, unique_pairs
 
 # R-MAT presets from Appendix A, Fig. 13.
 RMAT_PRESETS = {
@@ -68,7 +68,7 @@ def rmat_graph(
     lo = np.minimum(pairs[:, 0], pairs[:, 1])
     hi = np.maximum(pairs[:, 0], pairs[:, 1])
     keep = lo != hi
-    und = np.unique(np.stack([lo[keep], hi[keep]], axis=1), axis=0)
+    und = np.stack(unique_pairs(lo[keep], hi[keep]), axis=1)
     g = Graph.from_undirected_pairs(n, und, np.zeros(n, dtype=np.int32))
     if labeler == "degree":
         g.labels = degree_labels(g)
@@ -150,7 +150,6 @@ def planted_pattern_graph(
     scenarios, §1(iii)). Pattern copies attach to random background vertices by one edge."""
     rng = np.random.default_rng(seed)
     n0 = background.n
-    all_pairs = list(zip(background.src.tolist(), background.dst.tolist()))
     labels = [background.labels]
     extra = []
     for c in range(n_copies):
@@ -164,7 +163,6 @@ def planted_pattern_graph(
         labels.append(pattern.labels)
     src = np.concatenate([background.src, np.asarray([p[0] for p in extra], np.int32)])
     dst = np.concatenate([background.dst, np.asarray([p[1] for p in extra], np.int32)])
-    del all_pairs
     return Graph(
         n=n0 + n_copies * pattern.n,
         src=src,

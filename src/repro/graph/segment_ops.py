@@ -6,7 +6,7 @@ gather endpoint features with `jnp.take`, reduce by destination with
 candidate words) and every GNN aggregator route through these.
 
 Bitwise OR has no native XLA scatter combiner, so `segment_or` uses a
-*segmented associative scan* over dst-sorted edges with host-precomputed
+*segmented doubling scan* over dst-sorted edges with host-precomputed
 segment boundaries (static per graph). On TPU the `bitset_spmm` Pallas kernel
 replaces this path with a single VMEM-tiled edge sweep.
 """
@@ -55,8 +55,19 @@ def segment_or(values: jnp.ndarray, meta: SegmentMeta, num_segments: int) -> jnp
     m = values.shape[0]
     if m == 0:
         return jnp.zeros((num_segments,) + values.shape[1:], values.dtype)
-    flags = meta.is_start.reshape((m,) + (1,) * (values.ndim - 1))
-    scanned, _ = jax.lax.associative_scan(_seg_or_op, (values, flags))
+    trail = values.shape[1:]
+    flags = meta.is_start.reshape((m,) + (1,) * len(trail))
+    # Hillis-Steele doubling scan: ceil(log2 m) static shifts, each combining
+    # element i with element i - d under _seg_or_op. `associative_scan`'s
+    # strided odd/even slices cost the TPU compiler time and host memory
+    # linear in m (minutes and tens of GB at 31 M arcs); contiguous shifts
+    # compile in seconds at any m.
+    scanned, d = values, 1
+    while d < m:
+        prev = (jnp.concatenate([jnp.zeros((d,) + trail, values.dtype), scanned[:-d]]),
+                jnp.concatenate([jnp.ones((d,) + flags.shape[1:], bool), flags[:-d]]))
+        scanned, flags = _seg_or_op(prev, (scanned, flags))
+        d *= 2
     idx = meta.last_edge_of_vertex
     out = jnp.take(scanned, jnp.clip(idx, 0, m - 1), axis=0)
     mask = (idx >= 0).reshape((num_segments,) + (1,) * (values.ndim - 1))

@@ -18,6 +18,19 @@ import numpy as np
 import jax.numpy as jnp
 
 
+def unique_pairs(a: np.ndarray, b: np.ndarray):
+    """Distinct (a, b) pairs of non-negative ints in lexicographic order — what
+    `np.unique(np.stack([a, b], 1), axis=0)` returns, through one int64 key
+    sort instead of a row-wise one (seconds instead of minutes at 2^24 pairs)."""
+    a = np.asarray(a, dtype=np.int64)
+    b = np.asarray(b, dtype=np.int64)
+    if a.size == 0:
+        return a, b
+    base = int(b.max()) + 1
+    keys = np.unique(a * base + b)
+    return keys // base, keys % base
+
+
 @dataclasses.dataclass
 class Graph:
     """Host-side labeled graph. Directed edge list; undirected graphs store both arcs."""
@@ -48,8 +61,8 @@ class Graph:
         pairs = np.asarray(pairs, dtype=np.int64).reshape(-1, 2)
         pairs = pairs[pairs[:, 0] != pairs[:, 1]]
         both = np.concatenate([pairs, pairs[:, ::-1]], axis=0)
-        both = np.unique(both, axis=0)
-        return Graph(n=n, src=both[:, 0], dst=both[:, 1], labels=np.asarray(labels))
+        src, dst = unique_pairs(both[:, 0], both[:, 1])
+        return Graph(n=n, src=src, dst=dst, labels=np.asarray(labels))
 
     def csr(self):
         """Return (offsets int64[n+1], neighbors int32[m]) sorted by (src, dst)."""

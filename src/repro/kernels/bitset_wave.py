@@ -6,29 +6,28 @@ blocked OR-SpMM as `bitset_spmm`, each followed by a per-hop candidacy mask:
     F_r = (OR_{arc (u -> v) active} F_{r-1}[u]) & cand[r]        r = 1..L
 
 The single-hop route launches one `bitset_spmm` per hop, so every hop pays
-kernel-boundary traffic around the frontier (and, off-TPU, a pack/unpack
-round-trip through the oracle). Here the whole wave runs inside ONE
-`pallas_call` with the packed frontier resident in VMEM across all hops:
+kernel-boundary traffic around the frontier. Here the whole wave runs inside
+ONE `pallas_call` with the packed frontier resident in VMEM across all hops:
 
   grid = (L, nnzb) — hops major, dst-sorted adjacency blocks minor.
-  `cur` scratch uint32[n_pad, W] holds frontier F_{h}; the output block
-  (constant index map, VMEM-resident for the whole grid) accumulates F_{h+1}.
-  Per (h, b) step the (dst_block, src_block) bitmask is unpacked and
-  contracted against the cur rows of the src block on the MXU, exactly like
-  `bitset_spmm`; at each step the dst row of the output is rewritten as
-  pack(acc > 0) & cand[h] (final at the row's last block). At the first step
-  of hop h+1 the output buffer is copied into `cur` and zeroed — the only
-  frontier movement between hops is VMEM -> VMEM.
+  Two VMEM frontier planes ping-pong: an even hop reads the even plane and
+  writes the odd one, an odd hop the reverse. Per (h, b) step the
+  (dst_block, src_block) bitmask is unpacked and contracted against the src
+  block's rows on the MXU, exactly like `bitset_spmm`; at the dst row's last
+  block the row is written as pack(acc > 0) & cand[h]. The initial frontier
+  is copied from HBM into the even plane at the first step, and the final
+  plane is copied back to HBM at the last step — the only frontier traffic
+  between hops is VMEM -> VMEM.
 
-Pack/unpack therefore happens ONCE per wave (in the caller), not once per
-hop, and the per-hop block bitmasks are shared across hops (edge_active is
-constant within a wave).
+Rows of dst blocks that no pair visits are never written, so both planes are
+zeroed before they first receive a hop (odd at hop 0, even at hop 1);
+every visited row is rewritten in full at every hop.
 
-VMEM budget per step (bn=256, W=32, n_pad=2048):
-  cur + out 2 x 256 KiB, vals 256 KiB, acc 256x1024 f32 = 1 MiB,
-  mask block 8 KiB, cand row 8 KiB — ~1.8 MiB, comfortably inside 16 MiB.
-The ops-layer eligibility predicate rejects shapes whose resident frontier
-would blow the budget (huge n_pad x W), routing them to the oracle.
+Pack/unpack of the bool frontier happens ONCE per wave (in the caller), not
+once per hop, and the block bitmasks are shared across hops (edge_active is
+constant within a wave). `kernels/ops.py` counts the VMEM this kernel holds
+(`bitset_wave_vmem_bytes`) and routes shapes past the budget to the oracle;
+the same budget is passed to the compiler as the kernel's VMEM limit.
 """
 from __future__ import annotations
 
@@ -39,56 +38,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import compat
-from repro.kernels.bitset_spmm import _pack_bool_u32, _unpack_words_f32
+from repro.kernels.bitset_spmm import _accumulate, _as_i32, _pack_bits, _row_bounds
 
 
-def _kernel(pairs_ref, vals_ref, cand_ref, mask_ref, out_ref, cur_ref, acc_ref):
+def _zero(plane_ref, bn: int):
+    zeros = jnp.zeros((bn, plane_ref.shape[1]), plane_ref.dtype)
+
+    def body(i, carry):
+        plane_ref[pl.ds(pl.multiple_of(i * bn, bn), bn), :] = zeros
+        return carry
+
+    jax.lax.fori_loop(0, plane_ref.shape[0] // bn, body, 0)
+
+
+def _kernel(dst_ref, src_ref, mask_ref, cand_ref, vals_hbm, out_hbm, even_ref,
+            odd_ref, acc_ref, *, n_hops: int):
     h = pl.program_id(0)
     b = pl.program_id(1)
     bn = acc_ref.shape[0]
 
-    # hop boundary: load the initial frontier (hop 0) or advance the wave
-    # (copy last hop's completed output into cur), then clear the output —
-    # dst blocks no adjacency block touches must aggregate to zero.
     @pl.when(jnp.logical_and(h == 0, b == 0))
     def _load_initial():
-        cur_ref[...] = vals_ref[...]
-        out_ref[...] = jnp.zeros_like(out_ref)
+        compat.sync_copy(vals_hbm, even_ref)
+        _zero(odd_ref, bn)
 
-    @pl.when(jnp.logical_and(h > 0, b == 0))
-    def _advance_hop():
-        cur_ref[...] = out_ref[...]
-        out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(jnp.logical_and(h == 1, b == 0))
+    def _clear_hop0_plane():
+        _zero(even_ref, bn)
 
-    db = pairs_ref[b, 0]
-    sb = pairs_ref[b, 1]
-    prev_db = pairs_ref[jnp.maximum(b, 1) - 1, 0]
-    first = jnp.logical_or(b == 0, db != prev_db)
+    # hop h reads the even plane when h is even and writes the other one
+    even_hop = h % 2 == 0
+    db = dst_ref[b]
+    sb = src_ref[b]
+    first, last = _row_bounds(dst_ref, b, pl.num_programs(1))
+    src = pl.ds(pl.multiple_of(sb * bn, bn), bn)
+    src_rows = jnp.where(even_hop, even_ref[src, :], odd_ref[src, :])
+    _accumulate(acc_ref, mask_ref[0], src_rows, first)
 
-    mask_f = _unpack_words_f32(mask_ref[0])                     # [BN, BN]
-    src_rows = cur_ref[pl.ds(pl.multiple_of(sb * bn, bn), bn), :]
-    vals_f = _unpack_words_f32(src_rows)                        # [BN, 32W]
-    partial = jax.lax.dot_general(
-        mask_f, vals_f, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                           # [BN, 32W]
+    @pl.when(last)
+    def _emit():
+        row = pl.ds(pl.multiple_of(db * bn, bn), bn)
+        packed = _pack_bits(acc_ref[...] > 0.5)
+        packed = jnp.where(cand_ref[0] != 0, packed, 0)
 
-    @pl.when(first)
-    def _reset():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        @pl.when(even_hop)
+        def _to_odd():
+            odd_ref[row, :] = packed
 
-    acc_ref[...] += partial
-    # Rewritten every step of the dst row; final (and masked by this hop's
-    # candidacy) at the row's last block — nothing reads it before hop h+1.
-    row = pl.ds(pl.multiple_of(db * bn, bn), bn)
-    cw = cand_ref[0, row]
-    out_ref[row, :] = _pack_bool_u32(acc_ref[...] > 0.5) & cw[:, None]
+        @pl.when(jnp.logical_not(even_hop))
+        def _to_even():
+            even_ref[row, :] = packed
+
+    @pl.when(jnp.logical_and(h == n_hops - 1, b == pl.num_programs(1) - 1))
+    def _store_final():
+        compat.sync_copy(odd_ref if n_hops % 2 else even_ref, out_hbm)
+
+
+# VMEM the kernel may hold; `ops.bitset_wave_vmem_bytes` counts what a shape
+# needs and the eligibility gate admits only shapes within this budget.
+BITSET_WAVE_VMEM_BUDGET = 12 * 2**20
+# The (dst, src) step table of all blocks is scalar-prefetched into SMEM
+# (8 B a block) for the whole wave; the gate admits graphs up to this many.
+BITSET_WAVE_MAX_BLOCKS = 32768
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "n_pad", "interpret"))
 def bitset_wave(
     pairs: jnp.ndarray,   # int32[nnzb, 2] (dst_block, src_block), dst-sorted
-    masks: jnp.ndarray,   # uint32[nnzb, BN, BN//32] dynamic active bitmasks
+    masks: jnp.ndarray,   # int32[nnzb, 1, BN*BN//32] dynamic active bitmasks
     vals: jnp.ndarray,    # uint32[n_pad, W] packed initial frontier (hop 0)
     cand: jnp.ndarray,    # uint32[L, n_pad] per-hop candidacy, 0 / 0xFFFFFFFF
     *,
@@ -101,23 +118,29 @@ def bitset_wave(
     n_hops = cand.shape[0]
     w = vals.shape[1]
     grid_spec = compat.prefetch_scalar_grid_spec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(n_hops, nnzb),
         in_specs=[
-            pl.BlockSpec((n_pad, w), lambda h, b, pairs: (0, 0)),
-            pl.BlockSpec((1, n_pad), lambda h, b, pairs: (h, 0)),
-            pl.BlockSpec((1, bn, bn // 32), lambda h, b, pairs: (b, 0, 0)),
+            pl.BlockSpec((1, 1, bn * bn // 32), lambda h, b, d, s: (b, 0, 0)),
+            # one candidacy column per dst block row: [L, n_pad, 1] keeps the
+            # vertex axis on sublanes, where the packed rows live
+            pl.BlockSpec((1, bn, 1), lambda h, b, d, s: (h, d[b], 0)),
+            compat.any_spec(),
         ],
-        out_specs=pl.BlockSpec((n_pad, w), lambda h, b, pairs: (0, 0)),
+        out_specs=compat.any_spec(),
         scratch_shapes=[
-            compat.vmem((n_pad, w), jnp.uint32),
+            compat.vmem((n_pad, w), jnp.int32),
+            compat.vmem((n_pad, w), jnp.int32),
             compat.vmem((bn, 32 * w), jnp.float32),
         ],
     )
-    return compat.pallas_call(
-        _kernel,
+    out = compat.pallas_call(
+        functools.partial(_kernel, n_hops=n_hops),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, w), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n_pad, w), jnp.int32),
         interpret=interpret,
         dimension_semantics=("arbitrary", "arbitrary"),
-    )(pairs, vals, cand, masks)
+        vmem_limit_bytes=BITSET_WAVE_VMEM_BUDGET,
+    )(pairs[:, 0], pairs[:, 1], masks, _as_i32(cand)[:, :, None],
+      _as_i32(vals))
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)

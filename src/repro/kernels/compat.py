@@ -1,74 +1,62 @@
-"""Version-adaptive JAX/Pallas compatibility layer — the ONE choke point.
+"""JAX/Pallas API surface — the ONE choke point for the spellings this repo
+uses (requirements.txt pins the JAX line).
 
-Invariant (recorded in ROADMAP.md): every version-gated or backend-specific
-JAX API surface is resolved here and nowhere else. Concretely:
+Invariant (recorded in ROADMAP.md): every backend-specific or
+version-sensitive JAX API is spelled here and nowhere else:
 
-  - the TPU Pallas compiler-params class (``CompilerParams`` on newer JAX,
-    ``TPUCompilerParams`` on the 0.4.x line) — use :func:`tpu_compiler_params`
-    or, better, pass ``dimension_semantics=`` to :func:`pallas_call`,
-  - scratch/memory-space constructors (:func:`vmem`, :func:`smem`) and
+  - the TPU Pallas compiler params — pass ``dimension_semantics=`` and
+    ``vmem_limit_bytes=`` to :func:`pallas_call`,
+  - memory spaces and scratch constructors (:func:`vmem`,
+    :func:`any_spec`, :func:`sync_copy`) and
     :func:`prefetch_scalar_grid_spec`,
-  - mesh construction (:func:`make_mesh` accepts ``axis_types`` names on every
-    version and silently drops them where ``jax.sharding.AxisType`` does not
-    exist yet),
-  - :func:`shard_map` (moved from ``jax.experimental.shard_map`` to
-    ``jax.shard_map``; ``check_rep`` was renamed ``check_vma``).
+  - mesh construction with typed axes (:func:`make_mesh`, :func:`axis_type`),
+  - :func:`shard_map`,
+  - the persistent compilation cache (:func:`enable_compile_cache`).
 
-Kernel modules call :func:`pallas_call`; the dispatch registry
+There is one spelling per API, the installed JAX's. A JAX that lacks one
+fails loudly at the call; nothing here retries, drops an argument or falls
+back. Kernel modules call :func:`pallas_call`; the dispatch registry
 (``repro.kernels.registry``) decides compiled / interpret / reference per
-call. Nothing outside this file may import ``jax.experimental.pallas.tpu``
-symbols that differ across versions, spell a compiler-params class name, or
-touch ``jax.sharding.AxisType`` directly.
+call from eligibility alone, and a kernel that fails raises.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-from typing import Any, Optional, Sequence, Tuple
+import os
+from typing import Any, Optional, Sequence
 
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _version_tuple(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _version_tuple(jax.__version__)
-
-
 # ------------------------------------------------------------------ pallas
-# The TPU compiler-params class was renamed across the 0.4 -> 0.5 line.
-_TPU_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-
 def tpu_compiler_params(
-    *, dimension_semantics: Optional[Sequence[str]] = None, **kwargs
+    *,
+    dimension_semantics: Optional[Sequence[str]] = None,
+    vmem_limit_bytes: Optional[int] = None,
 ):
-    """Build the TPU compiler-params object for this JAX, or None when the
-    installed version exposes no such class (the kwarg is then omitted)."""
-    if _TPU_COMPILER_PARAMS_CLS is None:
-        return None
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _TPU_COMPILER_PARAMS_CLS(**kwargs)
+    """The TPU Pallas compiler-params object."""
+    return pltpu.CompilerParams(
+        dimension_semantics=(tuple(dimension_semantics)
+                             if dimension_semantics is not None else None),
+        vmem_limit_bytes=vmem_limit_bytes,
+    )
 
 
 def vmem(shape: Sequence[int], dtype) -> Any:
-    """VMEM scratch-shape constructor (pltpu.VMEM resolved here)."""
+    """VMEM scratch-shape constructor."""
     return pltpu.VMEM(tuple(shape), dtype)
 
 
-def smem(shape: Sequence[int], dtype) -> Any:
-    """SMEM scratch-shape constructor (pltpu.SMEM resolved here)."""
-    return pltpu.SMEM(tuple(shape), dtype)
+def any_spec() -> Any:
+    """BlockSpec for an operand left where it is (HBM); the kernel moves it
+    itself with :func:`sync_copy`."""
+    return pl.BlockSpec(memory_space=pl.ANY)
+
+
+def sync_copy(src_ref, dst_ref) -> None:
+    """Blocking DMA between two refs (HBM <-> VMEM) inside a kernel."""
+    pltpu.sync_copy(src_ref, dst_ref)
 
 
 def prefetch_scalar_grid_spec(
@@ -80,14 +68,7 @@ def prefetch_scalar_grid_spec(
     scratch_shapes: Sequence[Any] = (),
 ):
     """Scalar-prefetch grid spec (index maps may read the prefetched operands)."""
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:  # pragma: no cover - future JAX where it merges into pl
-        raise NotImplementedError(
-            "this JAX exposes no scalar-prefetch grid spec; extend "
-            "repro.kernels.compat.prefetch_scalar_grid_spec for "
-            f"jax=={jax.__version__}"
-        )
-    return cls(
+    return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=tuple(grid),
         in_specs=list(in_specs),
@@ -106,16 +87,13 @@ def pallas_call(
     out_specs=None,
     scratch_shapes: Sequence[Any] = (),
     dimension_semantics: Optional[Sequence[str]] = None,
+    vmem_limit_bytes: Optional[int] = None,
     interpret: bool = False,
     **extra,
 ):
-    """`pl.pallas_call` with the version differences absorbed.
-
-    Pass ``dimension_semantics`` directly; it is wrapped into whichever
-    compiler-params class this JAX spells. ``interpret=True`` runs the kernel
-    in the Pallas interpreter (the non-TPU path the registry dispatches for
-    ``force_pallas`` tests); a compiled call on a TPU backend leaves it False.
-    """
+    """`pl.pallas_call` with the TPU compiler params built from keywords.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the
+    off-TPU path the registry dispatches for ``force_pallas`` tests)."""
     kwargs = dict(out_shape=out_shape, interpret=interpret, **extra)
     if grid_spec is not None:
         kwargs["grid_spec"] = grid_spec
@@ -128,30 +106,11 @@ def pallas_call(
             kwargs["out_specs"] = out_specs
         if scratch_shapes:
             kwargs["scratch_shapes"] = list(scratch_shapes)
-    try:
-        # constructed inside the try: a params class that lost the
-        # dimension_semantics field is the same signature-drift case as a
-        # pallas_call that rejects compiler_params — both retry without it
-        params = tpu_compiler_params(dimension_semantics=dimension_semantics)
-        if params is not None:
-            kwargs["compiler_params"] = params
-        return pl.pallas_call(kernel, **kwargs)
-    except TypeError:
-        kwargs.pop("compiler_params", None)
-        return pl.pallas_call(kernel, **kwargs)
-
-
-# Exceptions that mean "this Pallas/JAX combination cannot express the kernel"
-# (renamed/removed API symbols, missing lowering) rather than a caller bug.
-# The registry traps these and falls back to the reference oracle unless
-# force_pallas is set. TypeError is deliberately NOT trapped: signature drift
-# is already absorbed by the pallas_call wrapper's own retry above, so a
-# TypeError escaping a kernel is almost always a real shape/dtype bug that
-# must surface, not be silently downgraded to the 8-32x-slower oracle.
-PALLAS_TRAP_ERRORS: Tuple[type, ...] = (
-    AttributeError,
-    NotImplementedError,
-)
+    if dimension_semantics is not None or vmem_limit_bytes is not None:
+        kwargs["compiler_params"] = tpu_compiler_params(
+            dimension_semantics=dimension_semantics,
+            vmem_limit_bytes=vmem_limit_bytes)
+    return pl.pallas_call(kernel, **kwargs)
 
 
 def on_tpu() -> bool:
@@ -159,29 +118,22 @@ def on_tpu() -> bool:
 
 
 # -------------------------------------------------------------------- mesh
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-_AXIS_TYPE_NAMES = ("auto", "explicit", "manual")
+_AXIS_TYPES = {
+    "auto": jax.sharding.AxisType.Auto,
+    "explicit": jax.sharding.AxisType.Explicit,
+    "manual": jax.sharding.AxisType.Manual,
+}
 
 
 def axis_type(kind: str = "auto"):
-    """Resolve an axis-type name ("auto" | "explicit" | "manual") to this
-    version's jax.sharding.AxisType member, or None where the enum does not
-    exist (pre-sharding-in-types JAX treats every axis as auto). Names are
-    validated on EVERY version so a typo fails identically everywhere."""
-    if kind not in _AXIS_TYPE_NAMES:
+    """Resolve an axis-type name ("auto" | "explicit" | "manual") to its
+    jax.sharding.AxisType member."""
+    try:
+        return _AXIS_TYPES[kind]
+    except KeyError:
         raise ValueError(
-            f"unknown axis type {kind!r}; expected one of {_AXIS_TYPE_NAMES}"
-        )
-    if not _HAS_AXIS_TYPE:
-        return None
-    enum = jax.sharding.AxisType
-    return {
-        "auto": enum.Auto,
-        "explicit": enum.Explicit,
-        "manual": enum.Manual,
-    }[kind]
+            f"unknown axis type {kind!r}; expected one of {tuple(_AXIS_TYPES)}"
+        ) from None
 
 
 def make_mesh(
@@ -191,56 +143,40 @@ def make_mesh(
     axis_types: Optional[Sequence[str]] = None,
     devices=None,
 ):
-    """`jax.make_mesh` that accepts axis-type *names* on every JAX version.
-
-    ``axis_types`` entries are strings ("auto"/"explicit"/"manual"); they are
-    resolved against this version's enum and dropped entirely where the
-    installed JAX predates typed mesh axes (its meshes are implicitly auto).
-    """
+    """`jax.make_mesh` taking axis-type *names* ("auto"/"explicit"/"manual")."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    maker = getattr(jax, "make_mesh", None)
-    if maker is None:  # pragma: no cover - ancient JAX
-        from jax.experimental import mesh_utils
-
-        devs = devices if devices is not None else mesh_utils.create_device_mesh(
-            tuple(axis_shapes)
-        )
-        return jax.sharding.Mesh(devs, tuple(axis_names))
     if axis_types is not None:
-        # resolve on every version: validates the names even where the enum
-        # is absent and the annotation is ultimately dropped
-        resolved = tuple(axis_type(t) for t in axis_types)
-    if axis_types is not None and _HAS_AXIS_TYPE:
-        if "axis_types" in inspect.signature(maker).parameters:
-            try:
-                return maker(
-                    tuple(axis_shapes), tuple(axis_names),
-                    axis_types=resolved, **kwargs,
-                )
-            except TypeError:
-                pass
-    return maker(tuple(axis_shapes), tuple(axis_names), **kwargs)
+        kwargs["axis_types"] = tuple(axis_type(t) for t in axis_types)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 # --------------------------------------------------------------- shard_map
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """shard_map across its module move and the check_rep->check_vma rename."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
+    """`jax.shard_map`; ``check_vma=None`` keeps JAX's default."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, **kwargs)
 
-    base = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is None:
-        return sm(f, **base)
-    # the replication-check kwarg was renamed check_rep -> check_vma; try the
-    # new spelling, then the old, and only then drop it (a caller passing
-    # False usually has a function that is NOT replication-safe, so silently
-    # re-enabling the check would break them at trace time)
-    for key in ("check_vma", "check_rep"):
-        try:
-            return sm(f, **base, **{key: check_vma})
-        except TypeError:
-            continue
-    return sm(f, **base)
+
+# ------------------------------------------------------- compilation cache
+# The fixed fallback location: the cache key includes the path, so it must
+# not move between runs (never a temporary name, a process id or the time).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already reads it and nothing is set here; otherwise the cache goes to
+    ``<repo>/.jax_cache``. Tests do not call this."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR

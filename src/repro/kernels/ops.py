@@ -5,8 +5,8 @@ every wrapper below registers its Pallas entrypoint, its pure-jnp oracle from
 ref.py, and a shape-eligibility predicate; per call the registry picks exactly
 one of pallas-compiled (eligible + TPU backend), pallas-interpret (eligible +
 force_pallas off-TPU — the kernel-parity test path), or the reference oracle
-(ineligible shapes, or off-TPU without force_pallas). A Pallas failure caused
-by JAX/Pallas API drift is trapped to the oracle unless force_pallas is set.
+(ineligible shapes, or off-TPU without force_pallas). A Pallas call that
+fails raises; `registry.count_dispatches()` shows which mode each call took.
 
 The wrappers own only pre/post-processing that is mode-independent (blocked
 mask construction, PNA mean/std derivation, long-sequence blockwise choice).
@@ -15,15 +15,17 @@ from __future__ import annotations
 
 from typing import Optional
 
-import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.graph.blocked import BlockedStructure, masks_from_active, pad_values
 from repro.kernels import ref as _ref
 from repro.kernels import registry
 from repro.kernels.bitset_spmm import bitset_spmm as _bitset_spmm_pallas
-from repro.kernels.bitset_wave import bitset_wave as _bitset_wave_pallas
+from repro.kernels.bitset_wave import (
+    BITSET_WAVE_MAX_BLOCKS,
+    BITSET_WAVE_VMEM_BUDGET,
+    bitset_wave as _bitset_wave_pallas,
+)
 from repro.kernels.segment_agg import (
     TILE_F as SEGMENT_AGG_TILE_F,
     TILE_N as SEGMENT_AGG_TILE_N,
@@ -41,14 +43,10 @@ ATTENTION_BLOCKWISE_CUTOFF = 2048
 def _bitset_pallas(vals, dg_src, dg_dst, n, edge_active, blocked, *, interpret):
     masks = masks_from_active(blocked, edge_active)
     out = _bitset_spmm_pallas(
-        jnp.asarray(blocked.pairs), masks, pad_values(vals, blocked),
+        blocked.device_arrays[0], masks, pad_values(vals, blocked),
         bn=blocked.bn, n_pad=blocked.n_pad, interpret=interpret,
     )
-    # dst blocks with no adjacency block are never visited by the grid
-    touched = np.zeros(blocked.n_pad // blocked.bn, dtype=bool)
-    touched[blocked.pairs[:, 0]] = True
-    trow = jnp.repeat(jnp.asarray(touched), blocked.bn)[:, None]
-    return jnp.where(trow, out, jnp.uint32(0))[:n]
+    return out[:n]
 
 
 def _bitset_ref(vals, dg_src, dg_dst, n, edge_active, blocked):
@@ -89,10 +87,22 @@ def bitset_or_aggregate(
 
 
 # ------------------------------------------------------------- bitset_wave
-# Resident state the fused wave keeps in VMEM: cur + out + vals frontiers
-# (uint32[n_pad, W] each), the f32 accumulator, one mask block, one candidacy
-# row. Shapes past this budget route to the oracle.
-BITSET_WAVE_VMEM_BUDGET = 12 * 2**20
+def _lanes(x: int) -> int:
+    return -(-x // 128) * 128
+
+
+def bitset_wave_vmem_bytes(n_pad: int, w: int, bn: int) -> int:
+    """VMEM the fused wave kernel needs for one shape, counted the way Mosaic
+    lays it out (lane dims padded to 128, pipelined blocks double-buffered):
+    the two resident frontier planes, the f32 accumulator plus the unpacked
+    bf16 planes and pack/unpack temporaries (six [bn, 32W] f32-sized slabs),
+    the mask and candidacy blocks, and the unpacked [bn, bn] mask. It bounds
+    the smallest `vmem_limit_bytes` the v5e compiler accepts for the shape
+    (tests/test_tpu_compile.py compiles the largest admitted shape)."""
+    frontier = 2 * n_pad * _lanes(w) * 4
+    slabs = 6 * bn * _lanes(32 * w) * 4
+    blocks = 2 * bn * _lanes(bn // 32) * 4 + 2 * bn * _lanes(1) * 4
+    return frontier + slabs + blocks + bn * _lanes(bn) * 4
 
 
 def _wave_pallas(vals, dg_src, dg_dst, n, edge_active, cand, blocked,
@@ -105,23 +115,17 @@ def _wave_pallas(vals, dg_src, dg_dst, n, edge_active, cand, blocked,
     cand_pad = jnp.zeros((cand.shape[0], blocked.n_pad), jnp.uint32)
     cand_pad = cand_pad.at[:, :n].set(cand)
     out = _bitset_wave_pallas(
-        jnp.asarray(blocked.pairs), masks, pad_values(vals, blocked), cand_pad,
+        blocked.device_arrays[0], masks, pad_values(vals, blocked), cand_pad,
         bn=blocked.bn, n_pad=blocked.n_pad, interpret=interpret,
     )
     return out[:n]
 
 
 def _wave_eligible(vals, dg_src, dg_dst, n, edge_active, cand, blocked):
-    if blocked is None:
+    if blocked is None or blocked.nnzb > BITSET_WAVE_MAX_BLOCKS:
         return False
-    w = int(vals.shape[-1])
-    resident = (
-        3 * blocked.n_pad * w * 4          # vals + cur scratch + out frontier
-        + blocked.bn * 32 * w * 4          # f32 accumulator
-        + blocked.bn * blocked.bnw * 4     # one mask block
-        + blocked.n_pad * 4                # one candidacy row
-    )
-    return resident <= BITSET_WAVE_VMEM_BUDGET
+    need = bitset_wave_vmem_bytes(blocked.n_pad, int(vals.shape[-1]), blocked.bn)
+    return need <= BITSET_WAVE_VMEM_BUDGET
 
 
 registry.register(

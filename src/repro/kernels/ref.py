@@ -11,10 +11,31 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.state import pack_bits, unpack_bits
-
 
 # ------------------------------------------------------------- bitset_spmm
+def _packed_or_hop(src, dst, n, edge_active):
+    """The OR-aggregate of packed words along the active dst-sorted arcs, as
+    a function words[n, W] -> words[n, W]: a segmented associative OR-scan
+    over the arcs, which stays in uint32 words (no bit planes)."""
+    from repro.graph import segment_ops
+
+    m = src.shape[0]
+    is_start = jnp.concatenate(
+        [jnp.ones((1,), bool), dst[1:] != dst[:-1]])
+    last_edge = jnp.full((n,), -1, jnp.int32).at[dst].max(
+        jnp.arange(m, dtype=jnp.int32))
+    meta = segment_ops.SegmentMeta(
+        is_start=is_start, last_edge_of_vertex=last_edge)
+    ea_word = jnp.where(edge_active, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+
+    def hop(packed):
+        msgs = jnp.take(packed, src, axis=0) & ea_word[:, None]
+        return segment_ops.segment_or(msgs, meta, n)
+
+    return hop
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
 def bitset_spmm_ref(
     vals: jnp.ndarray,         # uint32[n, W] packed
     src: jnp.ndarray,          # int32[m] dst-sorted
@@ -23,13 +44,9 @@ def bitset_spmm_ref(
     edge_active: jnp.ndarray,  # bool[m]
 ) -> jnp.ndarray:
     """out[v] = OR over active arcs (u -> v) of vals[u]."""
-    w = vals.shape[1]
-    bits = unpack_bits(vals, w * 32)                      # bool[n, 32W]
-    msgs = jnp.take(bits, src, axis=0) & edge_active[:, None]
-    agg = jax.ops.segment_max(
-        msgs.astype(jnp.int32), dst, num_segments=n, indices_are_sorted=True
-    ) > 0
-    return pack_bits(agg)
+    if src.shape[0] == 0:
+        return jnp.zeros((n, vals.shape[1]), jnp.uint32)
+    return _packed_or_hop(src, dst, n, edge_active)(vals)
 
 
 # ------------------------------------------------------------- bitset_wave
@@ -44,31 +61,17 @@ def bitset_wave_ref(
 ) -> jnp.ndarray:
     """Fused L-hop wave: F_r = OR-aggregate(F_{r-1}) & cand[r], r = 1..L.
 
-    Scan-based and pack/unpack-free: hops are a `lax.scan` over the hop-indexed
-    candidacy stack, and the per-hop aggregation stays in packed uint32 words
-    (a segmented associative OR-scan over the dst-sorted arcs — 32x fewer
-    aggregation bytes than the boolean-plane hop, with no bitset round-trip
-    per hop). The whole wave is one jitted XLA computation.
+    Hops are a `lax.scan` over the hop-indexed candidacy stack of the packed
+    hop of `bitset_spmm_ref`; the whole wave is one jitted XLA computation.
     """
-    from repro.graph import segment_ops
-
-    m = src.shape[0]
     if cand.shape[0] == 0:
         return vals
-    if m == 0:
+    if src.shape[0] == 0:
         return jnp.zeros_like(vals)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), dst[1:] != dst[:-1]])
-    last_edge = jnp.full((n,), -1, jnp.int32).at[dst].max(
-        jnp.arange(m, dtype=jnp.int32))
-    meta = segment_ops.SegmentMeta(
-        is_start=is_start, last_edge_of_vertex=last_edge)
-    ea_word = jnp.where(edge_active, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+    or_hop = _packed_or_hop(src, dst, n, edge_active)
 
     def hop(packed, cw):
-        msgs = jnp.take(packed, src, axis=0) & ea_word[:, None]
-        agg = segment_ops.segment_or(msgs, meta, n)
-        return agg & cw[:, None], None
+        return or_hop(packed) & cw[:, None], None
 
     out, _ = jax.lax.scan(hop, vals, cand)
     return out

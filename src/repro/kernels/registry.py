@@ -22,9 +22,11 @@ one of three modes (`resolve_mode` exposes the decision for tests):
   "ref"        reference oracle — ineligible shapes, or off-TPU without
                force_pallas
 
-A Pallas attempt that dies with an API-drift error (compat.PALLAS_TRAP_ERRORS)
-is trapped and re-run through the reference oracle — unless force_pallas was
-set, in which case the error propagates so parity tests stay strict.
+A Pallas call that fails raises: no error is turned into an oracle run, so
+a run that reports mode "pallas" ran the kernel. Routing to "ref" happens
+only by decision (ineligible shape, off-TPU, policy, `mode_override`), and
+`count_dispatches` makes every decision visible — `prune` reports the
+(kernel, mode) pairs of each run in stats["kernel_dispatches"].
 
 Dispatch policy
 ---------------
@@ -35,7 +37,7 @@ a per-(kernel, backend, shape-bucket) table of tuned decisions, produced by
 persisted to a JSON cache (`policy_path()`, overridable via the
 ``REPRO_DISPATCH_POLICY`` env var). `resolve_mode` consults the active policy
 first; with no policy (or no entry for the bucket) it falls back to the
-eligibility/trap behavior above, so an untuned checkout behaves exactly like
+eligibility rules above, so an untuned checkout behaves exactly like
 the pre-policy registry. `force_pallas` always bypasses the policy — parity
 tests pin the kernel path.
 
@@ -47,6 +49,7 @@ kernels/bitset_wave.py). `resolve_route` serves these to the hot loops.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import json
@@ -56,8 +59,6 @@ import warnings
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
-
-from repro.kernels import compat
 
 MODE_PALLAS = "pallas"
 MODE_INTERPRET = "interpret"
@@ -385,7 +386,7 @@ _POLICY: Any = _POLICY_UNSET
 
 def set_policy(policy: Optional[DispatchPolicy]) -> None:
     """Install `policy` as the active dispatch policy (None = explicitly no
-    policy: pure eligibility/trap fallback, no lazy cache load)."""
+    policy: eligibility rules only, no lazy cache load)."""
     global _POLICY
     _POLICY = policy
 
@@ -459,6 +460,30 @@ def dispatch_hook(hook: Callable[[str, str], None]):
         yield
     finally:
         set_dispatch_hook(prev)
+
+
+@contextlib.contextmanager
+def count_dispatches():
+    """Count the (kernel, mode) pairs dispatched inside the context: yields a
+    Counter keyed by (name, mode), filled as dispatches happen. A dispatch is
+    counted once per call of `dispatch` — once per trace for kernels inside a
+    jitted or looped program, once per call for eager ones. Any hook already
+    installed still runs first; a dispatch it rejects is not counted."""
+    counts: collections.Counter = collections.Counter()
+    prev = _DISPATCH_HOOK
+
+    def hook(name: str, mode: str) -> None:
+        if prev is not None:
+            prev(name, mode)
+        counts[(name, mode)] += 1
+
+    with dispatch_hook(hook):
+        yield counts
+
+
+def dispatch_report(counts) -> Dict[str, int]:
+    """`count_dispatches` counts as {"kernel:mode": n}, sorted."""
+    return {f"{name}:{mode}": int(n) for (name, mode), n in sorted(counts.items())}
 
 
 def _modes_runnable(backend: str) -> Tuple[str, ...]:
@@ -574,18 +599,7 @@ def dispatch(
         _DISPATCH_HOOK(name, mode)  # may raise: the fault-injection seam
     if mode == MODE_REF:
         return spec.ref_fn(*args, **kwargs)
-    try:
-        return spec.pallas_fn(*args, interpret=(mode == MODE_INTERPRET), **kwargs)
-    except compat.PALLAS_TRAP_ERRORS as e:
-        if force_pallas:
-            raise
-        warnings.warn(
-            f"pallas kernel {name!r} failed on jax=={jax.__version__} "
-            f"({type(e).__name__}: {e}); falling back to the reference oracle",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return spec.ref_fn(*args, **kwargs)
+    return spec.pallas_fn(*args, interpret=(mode == MODE_INTERPRET), **kwargs)
 
 
 # ---------------------------------------------------------------- autotune
@@ -645,8 +659,8 @@ def tune(
     path/persist  where (and whether) to save the JSON cache; the tuned
             policy is installed as the active one either way.
 
-    An interpret-mode candidate that traps on API drift is recorded as
-    unrunnable (inf) rather than aborting the tune.
+    A candidate that fails raises: a kernel that cannot run is a fault to
+    fix, not a losing measurement.
     """
     be = backend or jax.default_backend()
     pol = policy
@@ -673,11 +687,8 @@ def tune(
         bucket = spec.bucket(*args, **kwargs)
         measured: Dict[str, float] = {}
         for mode in _modes_runnable(be):
-            try:
-                measured[mode] = _time_thunk(
-                    _mode_thunk(spec, mode, args, kwargs), repeat)
-            except compat.PALLAS_TRAP_ERRORS:
-                measured[mode] = float("inf")
+            measured[mode] = _time_thunk(
+                _mode_thunk(spec, mode, args, kwargs), repeat)
         winner = min(measured, key=measured.get)
         pol.set_mode(name, be, bucket, winner, measured)
 

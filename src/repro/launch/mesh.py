@@ -1,7 +1,6 @@
 """Production meshes. Functions, not module constants — importing this module
 never touches jax device state (required by smoke tests that must see 1 CPU
-device). Construction goes through the version-adaptive compat layer so
-axis-type annotations degrade gracefully on JAX lines without
+device). Construction goes through the compat layer, which spells the
 typed mesh axes."""
 from __future__ import annotations
 

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_arch
 from repro.configs.base import LMConfig, RecsysConfig
-from repro.kernels import registry
+from repro.kernels import compat, registry
 from repro.models import transformer, bert4rec
 from repro import serve as serve_lib
 from repro.data import MaskedSequenceStream
@@ -55,6 +55,7 @@ def main():
                     help="dispatch-policy cache to serve under "
                          "(default: the registry's lazy policy_path() load)")
     args = ap.parse_args()
+    compat.enable_compile_cache()
 
     if args.policy:
         registry.set_policy(registry.DispatchPolicy.load(args.policy))

@@ -4,10 +4,11 @@
     plain CPU calls -> ref, for all four registered kernels,
   - parity: the interpret-mode Pallas path and the reference oracle agree
     (allclose / exact) through the SAME public ops wrapper,
-  - trap-to-ref: a Pallas entrypoint that dies with an API-drift error falls
-    back to the oracle unless force_pallas pins the kernel path,
-  - compat shims: make_mesh accepts axis-type names on this JAX, shard_map
-    resolves, packed NLCC frontier equals the boolean-plane wave.
+  - no hidden fallback: a Pallas entrypoint that fails raises; an ineligible
+    shape routes to the oracle by decision and shows in the dispatch counts,
+  - compat: make_mesh accepts axis-type names, shard_map and the TPU
+    compiler params take this JAX's one spelling; packed NLCC frontier
+    equals the boolean-plane wave.
 """
 import numpy as np
 import pytest
@@ -187,13 +188,15 @@ def test_embedding_bag_parity_through_wrapper():
                                rtol=1e-5, atol=1e-5)
 
 
-# ----------------------------------------------------------- trap-to-ref
-def test_trap_to_ref_falls_back_unless_forced():
+# ---------------------------------------------------- no hidden fallback
+@pytest.mark.parametrize("backend,force", [("tpu", False), ("cpu", True)],
+                         ids=["compiled", "interpret"])
+def test_failing_kernel_raises_instead_of_running_the_oracle(backend, force):
     calls = {"pallas": 0, "ref": 0}
 
     def broken_pallas(x, *, interpret):
         calls["pallas"] += 1
-        raise AttributeError("module has no attribute (simulated API drift)")
+        raise NotImplementedError("simulated lowering failure")
 
     def oracle(x):
         calls["ref"] += 1
@@ -201,15 +204,28 @@ def test_trap_to_ref_falls_back_unless_forced():
 
     registry.register("_test_broken", pallas=broken_pallas, ref=oracle)
     try:
-        with pytest.warns(RuntimeWarning, match="falling back"):
-            out = registry.dispatch("_test_broken", jnp.asarray(1),
-                                    backend="tpu")
-        assert int(out) == 2 and calls == {"pallas": 1, "ref": 1}
-        with pytest.raises(AttributeError):
-            registry.dispatch("_test_broken", jnp.asarray(1),
-                              force_pallas=True, backend="cpu")
+        with registry.count_dispatches() as counts:
+            with pytest.raises(NotImplementedError, match="simulated"):
+                registry.dispatch("_test_broken", jnp.asarray(1),
+                                  force_pallas=force, backend=backend)
+        assert calls == {"pallas": 1, "ref": 0}
+        mode = registry.MODE_PALLAS if backend == "tpu" else registry.MODE_INTERPRET
+        assert counts == {("_test_broken", mode): 1}
     finally:
         registry._REGISTRY.pop("_test_broken", None)
+
+
+def test_ineligible_shape_routes_to_ref_and_is_counted():
+    vals, src, dst, n, active, bs = _graph_args()
+    with registry.count_dispatches() as counts:
+        got = ops.bitset_or_aggregate(vals, src, dst, n, active, blocked=None,
+                                      force_pallas=True)
+        ops.bitset_or_aggregate(vals, src, dst, n, active, blocked=bs,
+                                force_pallas=True)
+    assert registry.dispatch_report(counts) == {
+        "bitset_spmm:interpret": 1, "bitset_spmm:ref": 1}
+    want = ref.bitset_spmm_ref(vals, src, dst, n, active)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_unknown_kernel_name_is_a_clear_error():
@@ -236,9 +252,10 @@ def test_shard_map_resolves_on_this_jax():
 
 
 def test_tpu_compiler_params_resolves_dimension_semantics():
-    params = compat.tpu_compiler_params(dimension_semantics=("arbitrary",))
-    assert params is not None
+    params = compat.tpu_compiler_params(dimension_semantics=("arbitrary",),
+                                        vmem_limit_bytes=2**20)
     assert tuple(params.dimension_semantics) == ("arbitrary",)
+    assert params.vmem_limit_bytes == 2**20
 
 
 # ----------------------------------------- packed NLCC frontier integration

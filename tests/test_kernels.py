@@ -34,6 +34,78 @@ def test_bitset_spmm_matches_ref(scale, w, bn):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("chunk,p_active", [(1, 0.7), (3, 0.7), (7, 0.05)])
+def test_bitset_spmm_chunked_sweep_matches_ref(chunk, p_active):
+    # chunks cut dst rows anywhere (a row's blocks then span two kernel
+    # calls) and dead blocks are skipped; neither may change a word
+    g = gen.rmat_graph(7, edge_factor=4, seed=chunk)
+    dg = DeviceGraph.from_host(g)
+    rng = np.random.default_rng(chunk)
+    vals = jnp.asarray(rng.integers(0, 2**32, size=(g.n, 2), dtype=np.uint32))
+    active = jnp.asarray(rng.random(dg.m) < p_active)
+    bs = build_blocked_structure(np.asarray(dg.src), np.asarray(dg.dst), g.n, bn=32)
+    got = bitset_spmm(
+        jnp.asarray(bs.pairs), masks_from_active(bs, active), pad_values(vals, bs),
+        bn=bs.bn, n_pad=bs.n_pad, interpret=True, chunk=chunk)[:g.n]
+    want = ref.bitset_spmm_ref(vals, dg.src, dg.dst, g.n, active)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("w", [1, 3])
+def test_bitset_spmm_ref_matches_numpy_or_scatter(w):
+    g = gen.erdos_renyi_graph(150, 2.0, seed=w)  # some vertices have no in-arcs
+    dg = DeviceGraph.from_host(g)
+    rng = np.random.default_rng(w)
+    vals = rng.integers(0, 2**32, size=(g.n, w), dtype=np.uint32)
+    active = rng.random(dg.m) < 0.6
+    src, dst = np.asarray(dg.src), np.asarray(dg.dst)
+    want = np.zeros_like(vals)
+    np.bitwise_or.at(want, dst[active], vals[src[active]])
+    got = ref.bitset_spmm_ref(jnp.asarray(vals), dg.src, dg.dst, g.n,
+                              jnp.asarray(active))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("hub_deg", [1, 37, 1000])
+def test_segment_or_matches_numpy_on_long_segments(hub_deg):
+    # the doubling scan must carry a segment across many shifts (a hub
+    # with hub_deg in-arcs) and leave vertices with no in-arcs at zero
+    from repro.graph import segment_ops
+
+    rng = np.random.default_rng(hub_deg)
+    dst = np.sort(np.concatenate([np.full(hub_deg, 5), rng.integers(0, 40, 301)]))
+    vals = rng.integers(0, 2**32, size=(dst.size, 2), dtype=np.uint32)
+    want = np.zeros((41, 2), np.uint32)
+    np.bitwise_or.at(want, dst, vals)
+    meta = segment_ops.build_segment_meta(dst, 41)
+    got = segment_ops.segment_or(jnp.asarray(vals), meta, 41)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_blocked_device_arrays_first_used_inside_a_trace():
+    g = gen.erdos_renyi_graph(200, 4.0, seed=2)
+    dg = DeviceGraph.from_host(g)
+    bs = build_blocked_structure(np.asarray(dg.src), np.asarray(dg.dst), g.n, bn=64)
+    active = jnp.asarray(np.random.default_rng(2).random(dg.m) < 0.5)
+    inside = jax.jit(lambda a: masks_from_active(bs, a))(active)
+    outside = masks_from_active(bs, active)  # reuses the cached arrays
+    np.testing.assert_array_equal(np.asarray(inside), np.asarray(outside))
+
+
+def test_unique_pairs_matches_rowwise_unique():
+    from repro.graph.structs import unique_pairs
+
+    pairs = np.random.default_rng(0).integers(0, 50, size=(2000, 2))
+    a, b = unique_pairs(pairs[:, 0], pairs[:, 1])
+    np.testing.assert_array_equal(np.stack([a, b], axis=1),
+                                  np.unique(pairs, axis=0))
+
+
+def test_blocked_structure_rejects_non_power_of_two_block():
+    with pytest.raises(ValueError, match="power of two"):
+        build_blocked_structure(np.zeros(1, np.int32), np.zeros(1, np.int32), 4, bn=96)
+
+
 def test_bitset_spmm_all_edges_inactive():
     g = gen.erdos_renyi_graph(100, 4.0, seed=0)
     dg = DeviceGraph.from_host(g)
@@ -110,7 +182,8 @@ def test_bitset_wave_zero_hops_is_identity():
 
 
 def test_bitset_wave_vmem_budget_gates_eligibility():
-    from repro.kernels.ops import _wave_eligible, BITSET_WAVE_VMEM_BUDGET
+    from repro.kernels.ops import (
+        _wave_eligible, bitset_wave_vmem_bytes, BITSET_WAVE_VMEM_BUDGET)
 
     g = gen.erdos_renyi_graph(256, 3.0, seed=1)
     dg = DeviceGraph.from_host(g)
@@ -119,10 +192,15 @@ def test_bitset_wave_vmem_budget_gates_eligibility():
     cand = jnp.ones((2, g.n), jnp.uint32)
     assert _wave_eligible(small, dg.src, dg.dst, g.n, None, cand, bs)
     # a frontier too wide to keep resident in VMEM must route to the oracle
-    huge_w = BITSET_WAVE_VMEM_BUDGET // (3 * bs.n_pad * 4) + 1
+    huge_w = 128
+    while bitset_wave_vmem_bytes(bs.n_pad, huge_w, bs.bn) <= BITSET_WAVE_VMEM_BUDGET:
+        huge_w += 128
     huge = jnp.ones((g.n, huge_w), jnp.uint32)
     assert not _wave_eligible(huge, dg.src, dg.dst, g.n, None, cand, bs)
     assert not _wave_eligible(small, dg.src, dg.dst, g.n, None, cand, None)
+    # lane padding: every packed width up to 128 words holds the same planes
+    assert (bitset_wave_vmem_bytes(8192, 1, 64) - 6 * 64 * 128 * 4
+            == bitset_wave_vmem_bytes(8192, 32, 64) - 6 * 64 * 1024 * 4)
 
 
 def test_blocked_masks_roundtrip():
@@ -130,14 +208,14 @@ def test_blocked_masks_roundtrip():
     g = gen.erdos_renyi_graph(300, 5.0, seed=3)
     dg = DeviceGraph.from_host(g)
     bs = build_blocked_structure(np.asarray(dg.src), np.asarray(dg.dst), g.n, bn=64)
-    masks = np.asarray(masks_from_active(bs, jnp.ones(dg.m, bool)))
+    masks = np.asarray(masks_from_active(bs, jnp.ones(dg.m, bool))).view(np.uint32)
     src, dst = np.asarray(dg.src), np.asarray(dg.dst)
     total_bits = sum(bin(int(x)).count("1") for x in masks.reshape(-1))
     assert total_bits == dg.m
     for e in np.random.default_rng(0).integers(0, dg.m, 20):
         b = bs.edge_block[e]
         r, c = dst[e] % bs.bn, src[e] % bs.bn
-        assert (masks[b, r, c // 32] >> (c % 32)) & 1 == 1
+        assert (masks[b, 0, r * bs.bnw + c // 32] >> (c % 32)) & 1 == 1
 
 
 # ------------------------------------------------------------- segment_agg
