@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+100 (1 - busy / window), busy being the union of the device operations'
+intervals averaged over the chips (bench/trace.py)."""
+
+
+def read(record):
+    tr = record.get("trace")
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

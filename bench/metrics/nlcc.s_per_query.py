@@ -1,0 +1,12 @@
+"""NLCC wave seconds per query: the cycle and path constraint entries
+("NLCC-cycle", "NLCC-path") of `prune`'s phase trajectory, summed over the
+window's answered queries and divided by their number."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from _phases import mean_per_query  # noqa: E402
+
+
+def read(record):
+    return mean_per_query(record, lambda name: name in ("NLCC-cycle", "NLCC-path"))

@@ -1,0 +1,12 @@
+"""Template-driven search seconds per query: the "NLCC-tds" entries of
+`prune`'s phase trajectory (host row joins), summed over the window's
+answered queries and divided by their number."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from _phases import mean_per_query  # noqa: E402
+
+
+def read(record):
+    return mean_per_query(record, lambda name: name == "NLCC-tds")
