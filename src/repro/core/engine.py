@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.graph.structs import Graph, DeviceGraph
 from repro.graph.partition import EdgePartition, partition_graph
 from repro.graph.segment_ops import SegmentMeta, segment_or
@@ -535,29 +536,35 @@ class LocalBackend:
 
         self._fire("lcc")
         dg, tdev, state = self.dg, self.tdev, self.state
-        if not self.edge_elimination:
-            self.state = self._lcc_no_edge_elim(stats)
-            return
-        if self.blocked is not None and not self.collect_stats and not tdev.needs_counts:
-            self.state = lcc_fixpoint_packed(
-                dg, tdev, state, self.blocked, stats=stats,
-                force_pallas=self.force_pallas)
-            return
-        if self.collect_stats:
-            # python loop to count per-iteration messages (active arcs at send time)
-            it = 0
-            while True:
-                stats["lcc_messages"] = stats.get("lcc_messages", 0) + int(
-                    jnp.sum(state.edge_active))
-                new_state, changed = lcc_iteration(dg, tdev, state)
-                it += 1
-                state = new_state
-                if not bool(changed) or it > 1000:
-                    break
-            stats["lcc_iterations"] = stats.get("lcc_iterations", 0) + it
-            self.state = state
-            return
-        self.state = lcc_fixpoint(dg, tdev, state, stats=stats)
+        with obs.span("lcc.fixpoint"):
+            if not self.edge_elimination:
+                self.state = self._lcc_no_edge_elim(stats)
+                return
+            if (self.blocked is not None and not self.collect_stats
+                    and not tdev.needs_counts):
+                self.state = lcc_fixpoint_packed(
+                    dg, tdev, state, self.blocked, stats=stats,
+                    force_pallas=self.force_pallas)
+                return
+            if self.collect_stats:
+                # python loop to count per-iteration messages (active arcs at
+                # send time)
+                it = 0
+                while True:
+                    active = int(obs.to_host(jnp.sum(state.edge_active),
+                                             "active_arcs"))
+                    stats["lcc_messages"] = stats.get("lcc_messages", 0) + active
+                    obs.count("active_arcs", active)
+                    new_state, changed = lcc_iteration(dg, tdev, state)
+                    it += 1
+                    state = new_state
+                    if not bool(obs.to_host(changed, "changed")) or it > 1000:
+                        break
+                stats["lcc_iterations"] = stats.get("lcc_iterations", 0) + it
+                obs.count("stepped_arcs", it * dg.m)
+                self.state = state
+                return
+            self.state = lcc_fixpoint(dg, tdev, state, stats=stats)
 
     def _lcc_no_edge_elim(self, stats: Dict) -> PruneState:
         """Vertex-elimination-only LCC (Fig. 6a baseline): edges stay active
@@ -576,8 +583,9 @@ class LocalBackend:
             )
             state = new_state
             it += 1
-            stats["lcc_messages"] = stats.get("lcc_messages", 0) + int(jnp.sum(ea))
-            if not bool(changed) or it > 1000:
+            stats["lcc_messages"] = stats.get("lcc_messages", 0) + int(
+                obs.to_host(jnp.sum(ea), "lcc_messages"))
+            if not bool(obs.to_host(changed, "changed")) or it > 1000:
                 break
         stats["lcc_iterations"] = stats.get("lcc_iterations", 0) + it
         return state

@@ -26,6 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.structs import DeviceGraph
 from repro.graph import segment_ops
 from repro.core.template import Template
@@ -153,21 +154,35 @@ def _fixpoint(iter_fn, state: PruneState, max_iters: int,
               stats: Optional[dict], extra_stat: Optional[str] = None
               ) -> PruneState:
     """Shared do-while driver: device while_loop so the whole fixpoint is a
-    single XLA computation (one dispatch). `iter_fn(state) -> (state, changed)`."""
+    single XLA computation (one dispatch). `iter_fn(state) -> (state, changed)`.
+
+    With `stats`, the loop also sums the active arcs at the start of each
+    sweep (as a uint32 pair, low word and carries: the sum passes 2**32
+    within a few hundred sweeps at tens of millions of arcs). The sum comes
+    back in the same read as the sweep count, as the open span's
+    `active_arcs`, beside `stepped_arcs`: the arcs the sweeps' per-arc
+    passes go over, every arc in every sweep."""
+    count = stats is not None
 
     def cond(carry):
-        st, changed, it = carry
-        return jnp.logical_and(changed, it < max_iters)
+        st, changed, n = carry
+        return jnp.logical_and(changed, n[0] < max_iters)
 
     def body(carry):
-        st, _, it = carry
+        st, _, n = carry  # n = [sweeps, active arcs low word, carries]
+        if count:
+            lo = n[1] + jnp.sum(st.edge_active, dtype=jnp.uint32)
+            n = jnp.stack([n[0], lo, n[2] + (lo < n[1]).astype(jnp.uint32)])
         st2, changed = iter_fn(st)
-        return st2, changed, it + 1
+        return st2, changed, n.at[0].add(1)
 
-    init = (state, jnp.asarray(True), jnp.asarray(0))
-    final_state, _, iters = jax.lax.while_loop(cond, body, init)
-    if stats is not None:
-        stats["lcc_iterations"] = stats.get("lcc_iterations", 0) + int(iters)
+    init = (state, jnp.asarray(True), jnp.zeros(3, jnp.uint32))
+    final_state, _, n = jax.lax.while_loop(cond, body, init)
+    if count:
+        it, lo, hi = (int(v) for v in obs.to_host(n, "lcc.sweeps"))
+        stats["lcc_iterations"] = stats.get("lcc_iterations", 0) + it
+        obs.count("active_arcs", (hi << 32) + lo)
+        obs.count("stepped_arcs", it * state.edge_active.shape[0])
         stats["lcc_calls"] = stats.get("lcc_calls", 0) + 1
         if extra_stat is not None:
             stats[extra_stat] = stats.get(extra_stat, 0) + 1

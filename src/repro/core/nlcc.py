@@ -23,12 +23,13 @@ Wave execution (`verify_constraint`) is batched: every walk of a constraint
 (all rotations of a cycle, both directions of a path) shares one candidacy
 stack built from the constraint-entry omega, per-wave survivors accumulate
 into a device-side `keep` plane, and the head-column eliminations are applied
-on device — the only host round-trips per constraint are the head-candidacy
-read that sizes the wave loop and (under `count_messages`) one message-count
-readback. Three tunable routes execute a wave: `unpacked` boolean planes
-(scan-based hops), `packed` per-hop bitset_spmm launches, and the `fused`
-multi-hop bitset_wave kernel (pack/unpack once per wave, frontier resident
-across hops).
+on device — the wave loop's only host round-trips per constraint are the
+head-candidacy read that sizes it and (under `count_messages`) one
+message-count readback; the edge-prune pass that may run first reads back
+more (see `verify_constraint`). Three tunable routes execute a wave:
+`unpacked` boolean planes (scan-based hops), `packed` per-hop bitset_spmm
+launches, and the `fused` multi-hop bitset_wave kernel (pack/unpack once per
+wave, frontier resident across hops).
 """
 from __future__ import annotations
 
@@ -39,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.structs import DeviceGraph
 from repro.graph import segment_ops
 from repro.core.template import NonLocalConstraint
@@ -369,9 +371,10 @@ def verify_constraint(
     shared state, per-wave survivors accumulate into a device-side `keep`
     plane, and the head-column eliminations (Alg. 5 line 8 — the heads are
     distinct template vertices across a constraint's walks) are applied on
-    device at the end. Host round-trips per constraint: one head-candidacy
-    read to size the wave loop, plus one message-count readback under
-    `count_messages` — never a per-wave `survived` transfer. Always sound (a
+    device at the end. The wave loop's host round-trips per constraint: one
+    head-candidacy read to size it, plus one message-count readback under
+    `count_messages` — never a per-wave `survived` transfer; these are what
+    `stats["nlcc_host_syncs"]` counts. Always sound (a
     token only survives by certifying a full walk, so no true match is ever
     pruned). For cycle rotations it is also exactly as strong as the old
     sequential per-rotation pass: a token completing rotation j through a
@@ -396,7 +399,11 @@ def verify_constraint(
     sound beyond-paper refinement (see walk_frontiers_and_edges): a true
     match realizes every hop of the walk, so an arc that is never
     (prefix-live, suffix-live) at any covering hop supports no match via
-    those template arcs."""
+    those template arcs. The pass reads back, per constraint: the head
+    column of omega; two bool[L, m] live planes per wave of `wave` heads;
+    omega, the arcs' endpoints and edge_active once (the endpoints move only
+    where JAX holds no host copy yet). `nlcc_host_syncs` does not count
+    these."""
     if edge_prune and template is not None:
         state = _edge_prune_pass(dg, state, constraint, template, wave, stats)
     walks = expand_walks(constraint, direction)
@@ -415,39 +422,41 @@ def verify_constraint(
     omega = state.omega
     n = omega.shape[0]
     heads = [w[0] for w in walks]
-    # ONE host sync per constraint: the head-candidacy columns size the wave
-    # loop (everything downstream stays on device)
-    head_cols = np.asarray(omega[:, jnp.asarray(heads, jnp.int32)])
+    # the wave loop's ONE host sync per constraint: the head-candidacy
+    # columns size it (everything downstream stays on device)
+    head_cols = obs.to_host(omega[:, jnp.asarray(heads, jnp.int32)], "nlcc.heads")
     host_syncs = 1
+    with obs.span("nlcc.sources", kind="host"):
+        walk_sources = [np.flatnonzero(head_cols[:, wi]) for wi in range(len(walks))]
     keep = jnp.zeros((len(walks), n), dtype=bool)
     total_msgs = jnp.asarray(0)
     n_waves = 0
     for wi, walk in enumerate(walks):
         cand = jnp.stack([omega[:, q] for q in walk], axis=0)  # bool[L+1, n]
-        sources = np.flatnonzero(head_cols[:, wi])
-        if sources.size == 0:
+        if walk_sources[wi].size == 0:
             continue
-        for ids_padded, n_real in wave_batches(sources, wave):
-            ids_dev = jnp.asarray(ids_padded, jnp.int32)
-            wave_state = PruneState(omega=omega, edge_active=state.edge_active)
-            if route == _registry.ROUTE_FUSED:
-                survived = check_walk_constraint_fused(
-                    dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                    blocked, force_pallas=force_pallas,
-                )
-            elif route == _registry.ROUTE_PACKED:
-                survived = check_walk_constraint_packed(
-                    dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                    blocked, force_pallas=force_pallas,
-                )
-            else:
-                survived, n_msgs = check_walk_constraint(
-                    dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                    count_messages=count_messages,
-                )
-                total_msgs = total_msgs + n_msgs
-            # pads clip to vertex 0 with survived=False — max() cannot unset
-            keep = keep.at[wi, jnp.clip(ids_dev, 0, n - 1)].max(survived)
+        for ids_padded, n_real in wave_batches(walk_sources[wi], wave):
+            with obs.span("nlcc.wave"):
+                ids_dev = jnp.asarray(ids_padded, jnp.int32)
+                wave_state = PruneState(omega=omega, edge_active=state.edge_active)
+                if route == _registry.ROUTE_FUSED:
+                    survived = check_walk_constraint_fused(
+                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
+                        blocked, force_pallas=force_pallas,
+                    )
+                elif route == _registry.ROUTE_PACKED:
+                    survived = check_walk_constraint_packed(
+                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
+                        blocked, force_pallas=force_pallas,
+                    )
+                else:
+                    survived, n_msgs = check_walk_constraint(
+                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
+                        count_messages=count_messages,
+                    )
+                    total_msgs = total_msgs + n_msgs
+                # pads clip to vertex 0 with survived=False — max() cannot unset
+                keep = keep.at[wi, jnp.clip(ids_dev, 0, n - 1)].max(survived)
             n_waves += 1
             if stats is not None:
                 stats["nlcc_tokens"] = stats.get("nlcc_tokens", 0) + n_real
@@ -457,7 +466,8 @@ def verify_constraint(
         omega = omega.at[:, q0].set(omega[:, q0] & keep[wi])
     if stats is not None:
         if count_messages:
-            stats["nlcc_messages"] = stats.get("nlcc_messages", 0) + int(total_msgs)
+            stats["nlcc_messages"] = stats.get("nlcc_messages", 0) + int(
+                obs.to_host(total_msgs, "nlcc_messages"))
             host_syncs += 1
         stats["nlcc_constraints"] = stats.get("nlcc_constraints", 0) + 1
         stats["nlcc_waves"] = stats.get("nlcc_waves", 0) + n_waves
@@ -475,43 +485,52 @@ def _edge_prune_pass(
     stats: Optional[Dict],
 ) -> PruneState:
     """Forward-backward frontier edge elimination for one CC/PC constraint."""
-    walk = list(constraint.walk)
-    l = len(walk) - 1
-    omega = state.omega
-    cand = jnp.stack([omega[:, q] for q in walk], axis=0)
-    sources = np.flatnonzero(np.asarray(omega[:, walk[0]]))
-    if sources.size == 0:
-        return state
-    m = dg.m
-    live_f = np.zeros((l, m), dtype=bool)
-    live_r = np.zeros((l, m), dtype=bool)
-    for idsp, _ in wave_batches(sources, wave):
-        _, fl, rl = walk_frontiers_and_edges(
-            dg, state, cand, constraint.is_cyclic, jnp.asarray(idsp, jnp.int32))
-        live_f |= np.asarray(fl)
-        live_r |= np.asarray(rl)
+    with obs.span("nlcc.edge_prune"):
+        walk = list(constraint.walk)
+        l = len(walk) - 1
+        omega = state.omega
+        cand = jnp.stack([omega[:, q] for q in walk], axis=0)
+        head = obs.to_host(omega[:, walk[0]], "edge_prune.heads")
+        with obs.span("nlcc.sources", kind="host"):
+            sources = np.flatnonzero(head)
+        if sources.size == 0:
+            return state
+        m = dg.m
+        live_f = np.zeros((l, m), dtype=bool)
+        live_r = np.zeros((l, m), dtype=bool)
+        for idsp, _ in wave_batches(sources, wave):
+            _, fl, rl = walk_frontiers_and_edges(
+                dg, state, cand, constraint.is_cyclic, jnp.asarray(idsp, jnp.int32))
+            fl = obs.to_host(fl, "edge_prune.fwd_live")
+            rl = obs.to_host(rl, "edge_prune.rev_live")
+            with obs.span("nlcc.edge_prune.support", kind="host"):
+                live_f |= fl
+                live_r |= rl
 
-    pairs = list(zip(walk[:-1], walk[1:]))
-    covered: Dict[tuple, list] = {}
-    for i, (qa, qb) in enumerate(pairs):
-        covered.setdefault((qa, qb), []).append(("f", i))
-        covered.setdefault((qb, qa), []).append(("r", i))
+        pairs = list(zip(walk[:-1], walk[1:]))
+        covered: Dict[tuple, list] = {}
+        for i, (qa, qb) in enumerate(pairs):
+            covered.setdefault((qa, qb), []).append(("f", i))
+            covered.setdefault((qb, qa), []).append(("r", i))
 
-    om = np.asarray(omega)
-    src, dst = np.asarray(dg.src), np.asarray(dg.dst)
-    support = np.zeros(m, dtype=bool)
-    for qa in range(template.n0):
-        for qb in template.adj[qa]:
-            lcc_rule = om[src, qa] & om[dst, qb]
-            if (qa, qb) in covered:
-                live = np.zeros(m, dtype=bool)
-                for kind, i in covered[(qa, qb)]:
-                    live |= live_f[i] if kind == "f" else live_r[i]
-                support |= lcc_rule & live
-            else:
-                support |= lcc_rule
-    new_ea = np.asarray(state.edge_active) & support
-    if stats is not None:
-        stats["nlcc_edges_pruned"] = stats.get("nlcc_edges_pruned", 0) + int(
-            np.sum(np.asarray(state.edge_active)) - np.sum(new_ea))
-    return PruneState(omega=omega, edge_active=jnp.asarray(new_ea))
+        om = obs.to_host(omega, "omega")
+        src = obs.to_host(dg.src, "src")
+        dst = obs.to_host(dg.dst, "dst")
+        ea = obs.to_host(state.edge_active, "edge_active")
+        with obs.span("nlcc.edge_prune.support", kind="host"):
+            support = np.zeros(m, dtype=bool)
+            for qa in range(template.n0):
+                for qb in template.adj[qa]:
+                    lcc_rule = om[src, qa] & om[dst, qb]
+                    if (qa, qb) in covered:
+                        live = np.zeros(m, dtype=bool)
+                        for kind, i in covered[(qa, qb)]:
+                            live |= live_f[i] if kind == "f" else live_r[i]
+                        support |= lcc_rule & live
+                    else:
+                        support |= lcc_rule
+            new_ea = ea & support
+            if stats is not None:
+                stats["nlcc_edges_pruned"] = stats.get("nlcc_edges_pruned", 0) + int(
+                    np.sum(ea) - np.sum(new_ea))
+        return PruneState(omega=omega, edge_active=jnp.asarray(new_ea))

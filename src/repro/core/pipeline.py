@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.structs import Graph, DeviceGraph
 from repro.core.template import Template, generate_constraints, NonLocalConstraint
 from repro.core.state import PruneState
@@ -156,87 +157,93 @@ def prune(
     checkpointing, the per-phase degradation ladder, deterministic fault
     injection (when the config carries a FaultInjector), and elastic
     restart/rebalance — see the module docstring and core/resilience.py."""
-    if isinstance(graph, Graph) and label_freq is None:
-        label_freq = graph.label_frequency()
+    # every span of one request shares the root span's id as `query`
+    with obs.span("prune", n0=template.n0) as root:
+        if isinstance(graph, Graph) and label_freq is None:
+            with obs.span("prune.plan", kind="host"):
+                label_freq = graph.label_frequency()
 
-    backend_kw = dict(
-        wave=wave, blocked=blocked, force_pallas=force_pallas,
-        edge_elimination=edge_elimination, collect_stats=collect_stats,
-        nlcc_edge_prune=nlcc_edge_prune, tds_chunk=tds_chunk,
-        tds_max_rows=tds_max_rows, work_aggregation=work_aggregation,
-        guarantee_precision=guarantee_precision,
-    )
-    if resilience is not None and resilience.injector is not None:
-        backend_kw["injector"] = resilience.injector
-    backend = engine_mod.make_backend(
-        graph, template, mesh=mesh, partition=partition, **backend_kw)
-    dg = backend.dg
-    stats: Dict = {"edge_elimination": edge_elimination,
-                   "work_aggregation": work_aggregation,
-                   "backend": backend.name}
-    if resilience is not None:
-        stats["resilience"] = {
-            "checkpoints": 0, "checkpoint_seconds": [], "restarts": [],
-            "rebalances": [], "ladder": [], "recovery_seconds": 0.0,
+        backend_kw = dict(
+            wave=wave, blocked=blocked, force_pallas=force_pallas,
+            edge_elimination=edge_elimination, collect_stats=collect_stats,
+            nlcc_edge_prune=nlcc_edge_prune, tds_chunk=tds_chunk,
+            tds_max_rows=tds_max_rows, work_aggregation=work_aggregation,
+            guarantee_precision=guarantee_precision,
+        )
+        if resilience is not None and resilience.injector is not None:
+            backend_kw["injector"] = resilience.injector
+        backend = engine_mod.make_backend(
+            graph, template, mesh=mesh, partition=partition, **backend_kw)
+        dg = backend.dg
+        stats: Dict = {"edge_elimination": edge_elimination,
+                       "work_aggregation": work_aggregation,
+                       "backend": backend.name}
+        if resilience is not None:
+            stats["resilience"] = {
+                "checkpoints": 0, "checkpoint_seconds": [], "restarts": [],
+                "rebalances": [], "ladder": [], "recovery_seconds": 0.0,
+            }
+
+        backend.init(initial_state)
+        if template.n0 == 1:
+            return PruneResult(backend.final_state(), template, dg, [], stats,
+                               backend=backend)
+
+        backend.record_routes(stats)  # each backend decides what (if anything) to record
+
+        # Beyond-paper fast path: with forward-backward frontier edge pruning,
+        # CC alone yields the exact edge set for unique-label edge-monocyclic
+        # templates (every surviving edge lies on a completing label-cycle, and
+        # unique labels make any such cycle a true match) — the complete-walk TDS
+        # becomes unnecessary. Validated against the oracle in the property tests.
+        skip_complete = (
+            nlcc_edge_prune and guarantee_precision
+            and not template.is_acyclic()
+            and template.is_edge_monocyclic() and not template.repeated_labels()
+        )
+        if skip_complete:
+            stats["tds_skipped_via_frontier_edge_prune"] = True
+        with obs.span("prune.plan", kind="host"):
+            # The constraint list is fixed ONCE, from the original graph's label
+            # frequencies — an elastic restart must replay the identical phases.
+            if constraints is None:
+                constraints = generate_constraints(
+                    template, label_freq=label_freq,
+                    guarantee_precision=guarantee_precision and not skip_complete,
+                )
+                if plan is None:
+                    # plan-level optimizer lookup (core/planner.py): only when the
+                    # active policy carries tuned plans — an untuned checkout never
+                    # touches graph stats and runs the heuristic order
+                    # byte-identically
+                    plan = _maybe_resolve_plan(graph, dg, template, constraints,
+                                               label_freq)
+            if plan is not None:
+                _check_plan(plan, constraints)
+                constraints = plan.constraints()
+            else:
+                plan = planner_mod.heuristic_plan(constraints)
+        stats["n_constraints"] = len(constraints)
+        root.attrs["constraints"] = len(constraints)
+        stats["plan"] = {
+            "source": plan.source,
+            "phases": [
+                {"sig": p.signature, "engine": p.engine,
+                 "direction": p.direction,
+                 "predicted_s": (plan.per_phase_s[i] if plan.per_phase_s
+                                 else None),
+                 "actual_s": None}
+                for i, p in enumerate(plan.phases)
+            ],
         }
 
-    backend.init(initial_state)
-    if template.n0 == 1:
-        return PruneResult(backend.final_state(), template, dg, [], stats,
-                           backend=backend)
-
-    backend.record_routes(stats)  # each backend decides what (if anything) to record
-
-    # Beyond-paper fast path: with forward-backward frontier edge pruning,
-    # CC alone yields the exact edge set for unique-label edge-monocyclic
-    # templates (every surviving edge lies on a completing label-cycle, and
-    # unique labels make any such cycle a true match) — the complete-walk TDS
-    # becomes unnecessary. Validated against the oracle in the property tests.
-    skip_complete = (
-        nlcc_edge_prune and guarantee_precision
-        and not template.is_acyclic()
-        and template.is_edge_monocyclic() and not template.repeated_labels()
-    )
-    if skip_complete:
-        stats["tds_skipped_via_frontier_edge_prune"] = True
-    # The constraint list is fixed ONCE, from the original graph's label
-    # frequencies — an elastic restart must replay the identical phases.
-    if constraints is None:
-        constraints = generate_constraints(
-            template, label_freq=label_freq,
-            guarantee_precision=guarantee_precision and not skip_complete,
+        driver = _Driver(
+            graph=graph, template=template, backend=backend, dg=dg, stats=stats,
+            plan=plan, res=resilience, collect_stats=collect_stats,
+            mesh=mesh, backend_kw=backend_kw, initial_state=initial_state,
         )
-        if plan is None:
-            # plan-level optimizer lookup (core/planner.py): only when the
-            # active policy carries tuned plans — an untuned checkout never
-            # touches graph stats and runs the heuristic order byte-identically
-            plan = _maybe_resolve_plan(graph, dg, template, constraints,
-                                       label_freq)
-    if plan is not None:
-        _check_plan(plan, constraints)
-        constraints = plan.constraints()
-    else:
-        plan = planner_mod.heuristic_plan(constraints)
-    stats["n_constraints"] = len(constraints)
-    stats["plan"] = {
-        "source": plan.source,
-        "phases": [
-            {"sig": p.signature, "engine": p.engine,
-             "direction": p.direction,
-             "predicted_s": (plan.per_phase_s[i] if plan.per_phase_s
-                             else None),
-             "actual_s": None}
-            for i, p in enumerate(plan.phases)
-        ],
-    }
-
-    driver = _Driver(
-        graph=graph, template=template, backend=backend, dg=dg, stats=stats,
-        plan=plan, res=resilience, collect_stats=collect_stats,
-        mesh=mesh, backend_kw=backend_kw, initial_state=initial_state,
-    )
-    driver.run()
-    return driver.finish()
+        driver.run()
+        return driver.finish()
 
 
 def _maybe_resolve_plan(graph, dg, template, constraints, label_freq):
@@ -251,7 +258,7 @@ def _maybe_resolve_plan(graph, dg, template, constraints, label_freq):
         st = gstats.collect_graph_stats(graph)
     else:
         nl = (len(label_freq) if label_freq is not None
-              else int(np.asarray(dg.labels).max()) + 1)
+              else int(obs.to_host(dg.labels, "labels").max()) + 1)
         st = gstats.collect_graph_stats(dg, n_labels=nl)
     return planner_mod.resolve_query_plan(template, constraints, st)
 
@@ -308,38 +315,45 @@ class _Driver:
 
     # -- phase bodies -------------------------------------------------------
     def _phase_initial(self):
-        t0 = time.perf_counter()
-        self.backend.lcc(self.stats)
-        self._snap("LCC", None, t0, {})
+        with obs.span("prune.phase", phase="LCC") as sp:
+            self.backend.lcc(self.stats)
+            self._fence()
+        self._snap("LCC", None, sp.seconds, {})
 
     def _phase_constraint(self, k: int):
         p = self.phases[k - 1]
         c = p.constraint
-        t0 = time.perf_counter()
+        phase = f"NLCC-{c.kind}"
         cstats: Dict = {}
-        if p.engine == planner_mod.ENGINE_NLCC:
-            changed = self.backend.nlcc(c, cstats, direction=p.direction)
-        else:
-            changed = self.backend.tds(c, cstats)
-        self._snap(f"NLCC-{c.kind}", str(c.walk), t0, cstats)
-        # predicted-vs-actual for the plan report; assignment (not +=) so a
-        # resilience replay of the phase records only the committed attempt
-        self.stats["plan"]["phases"][k - 1]["actual_s"] = (
-            time.perf_counter() - t0)
+        with obs.span("prune.phase", phase=phase) as sp:
+            if p.engine == planner_mod.ENGINE_NLCC:
+                changed = self.backend.nlcc(c, cstats, direction=p.direction)
+            else:
+                changed = self.backend.tds(c, cstats)
+            self._fence()
+        self._snap(phase, str(c.walk), sp.seconds, cstats)
+        # predicted-vs-actual for the plan report, the phase span's duration;
+        # assignment (not +=) so a resilience replay of the phase records
+        # only the committed attempt
+        self.stats["plan"]["phases"][k - 1]["actual_s"] = sp.seconds
         # ONE device bool decides the re-run — not six blocking count reads
-        if bool(changed):
-            t0 = time.perf_counter()
-            self.backend.lcc(self.stats)
-            self._snap("LCC", None, t0, {})
+        if bool(obs.to_host(changed, "changed")):
+            with obs.span("prune.phase", phase="LCC") as sp:
+                self.backend.lcc(self.stats)
+                self._fence()
+            self._snap("LCC", None, sp.seconds, {})
 
-    def _snap(self, phase, cname, t0, extra):
+    def _fence(self):
         # the phase's wall time must include its device work (the recorded
         # perf trajectory compares PR-over-PR), so fence the stream — a sync
-        # with NO transfer — before timestamping. The snapshot counts stay a
-        # lazy device value until ONE materialization at the end of the run;
-        # eager host counts only under collect_stats=True (satellite of PR 4)
-        self.backend.sync()
-        secs = time.perf_counter() - t0
+        # with NO transfer — before the phase span closes
+        with obs.span("prune.fence"):
+            self.backend.sync()
+
+    def _snap(self, phase, cname, secs, extra):
+        # The snapshot counts stay a lazy device value until ONE
+        # materialization at the end of the run; eager host counts only
+        # under collect_stats=True (satellite of PR 4)
         counts = (self.backend.counts_host() if self.collect_stats
                   else self.backend.counts_dev())
         self._stage.append((phase, cname, secs, extra, counts))
@@ -662,7 +676,7 @@ def _materialize(raw_phases: List[tuple]) -> List[PhaseStat]:
     counts are stacked and transferred in ONE host sync."""
     deferred = [c for *_, c in raw_phases if not isinstance(c, dict)]
     if deferred:
-        mat = iter(np.asarray(jnp.stack(deferred)))
+        mat = iter(obs.to_host(jnp.stack(deferred), "phase_counts"))
     phases: List[PhaseStat] = []
     for phase, cname, secs, extra, counts in raw_phases:
         if isinstance(counts, dict):

@@ -16,6 +16,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.structs import DeviceGraph
 from repro.core.template import Template
 
@@ -66,7 +67,8 @@ class PruneState:
 
 def init_state(dg: DeviceGraph, template: Template) -> PruneState:
     """Alg. 2 initialization: omega(v) = {q : l(q) == l(v)}; all edges active."""
-    n_labels = max(int(template.labels.max()) + 1, int(jnp.max(dg.labels)) + 1)
+    n_labels = max(int(template.labels.max()) + 1,
+                   int(obs.to_host(jnp.max(dg.labels), "labels_max")) + 1)
     lm = jnp.asarray(template.label_matrix(n_labels))  # [n0, L]
     omega = jnp.take(lm.T, dg.labels, axis=0)  # [n, n0]
     edge_active = jnp.ones((dg.m,), dtype=bool)

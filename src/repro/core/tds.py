@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.graph.structs import DeviceGraph
 from repro.core.state import PruneState
 from repro.core.template import Template, NonLocalConstraint
@@ -51,10 +52,10 @@ class ActiveSubgraph:
 
 
 def compact_active(dg: DeviceGraph, state: PruneState) -> ActiveSubgraph:
-    src = np.asarray(dg.src)
-    dst = np.asarray(dg.dst)
-    omega = np.asarray(state.omega)
-    ea = np.asarray(state.edge_active)
+    src = obs.to_host(dg.src, "src")
+    dst = obs.to_host(dg.dst, "dst")
+    omega = obs.to_host(state.omega, "omega")
+    ea = obs.to_host(state.edge_active, "edge_active")
     vact = omega.any(axis=1)
     keep = ea & vact[src] & vact[dst]
     s, d = src[keep], dst[keep]
@@ -231,57 +232,61 @@ def verify_tds_constraint(
     """
     import jax.numpy as jnp
 
-    sub = compact_active(dg, state)
-    q0 = constraint.walk[0]
-    sources = np.flatnonzero(sub.omega[:, q0])
-    survived_all = np.zeros(sub.n, dtype=bool)
-    confirmed = np.zeros_like(sub.omega) if annotate else None
-    confirmed_arc_keys: list = []
+    # the compaction and the row join are host work over the arcs; the reads
+    # of the graph and the state inside them are `host.readback` spans
+    with obs.span("tds.join", kind="host"):
+        sub = compact_active(dg, state)
+        q0 = constraint.walk[0]
+        sources = np.flatnonzero(sub.omega[:, q0])
+        survived_all = np.zeros(sub.n, dtype=bool)
+        confirmed = np.zeros_like(sub.omega) if annotate else None
+        confirmed_arc_keys: list = []
 
-    walk_pairs = sorted({(min(a, b), max(a, b))
-                         for a, b in zip(constraint.walk[:-1], constraint.walk[1:])})
+        walk_pairs = sorted({(min(a, b), max(a, b))
+                             for a, b in zip(constraint.walk[:-1], constraint.walk[1:])})
 
-    off = 0
-    cur_chunk = chunk
-    while off < sources.size:
-        ids = sources[off : off + cur_chunk]
-        try:
-            surv, rows, seen_q = tds_walk(
-                sub, constraint.walk, ids, max_rows=max_rows,
-                collect_rows=annotate, stats=stats, dedup=dedup,
+        off = 0
+        cur_chunk = chunk
+        while off < sources.size:
+            ids = sources[off : off + cur_chunk]
+            try:
+                surv, rows, seen_q = tds_walk(
+                    sub, constraint.walk, ids, max_rows=max_rows,
+                    collect_rows=annotate, stats=stats, dedup=dedup,
+                )
+            except TdsOverflow:
+                if cur_chunk == 1:
+                    raise
+                cur_chunk = max(1, cur_chunk // 4)  # paper's rate control
+                continue
+            survived_all[ids[surv]] = True
+            if annotate and rows is not None and rows.shape[0]:
+                col = {q: c for c, q in enumerate(seen_q)}
+                for c, q in enumerate(seen_q):
+                    confirmed[rows[:, c], q] = True
+                # confirmed edges: every template edge of every completed walk
+                for a, b in walk_pairs:
+                    u, v = rows[:, col[a]].astype(np.int64), rows[:, col[b]].astype(np.int64)
+                    confirmed_arc_keys.append(np.unique(u * sub.n + v))
+                    confirmed_arc_keys.append(np.unique(v * sub.n + u))
+            off += ids.size
+        omega = obs.to_host(state.omega, "omega").copy()
+        omega[:, q0] &= survived_all
+        edge_active = state.edge_active
+        if annotate:
+            if not constraint.complete:
+                raise ValueError("annotate requires a complete walk")
+            omega = confirmed & obs.to_host(state.omega, "omega")
+            # exact edge set (paper: the output G* contains only edges of matches)
+            keys = (
+                np.unique(np.concatenate(confirmed_arc_keys))
+                if confirmed_arc_keys
+                else np.zeros(0, np.int64)
             )
-        except TdsOverflow:
-            if cur_chunk == 1:
-                raise
-            cur_chunk = max(1, cur_chunk // 4)  # paper's rate control
-            continue
-        survived_all[ids[surv]] = True
-        if annotate and rows is not None and rows.shape[0]:
-            col = {q: c for c, q in enumerate(seen_q)}
-            for c, q in enumerate(seen_q):
-                confirmed[rows[:, c], q] = True
-            # confirmed edges: every template edge of every completed walk
-            for a, b in walk_pairs:
-                u, v = rows[:, col[a]].astype(np.int64), rows[:, col[b]].astype(np.int64)
-                confirmed_arc_keys.append(np.unique(u * sub.n + v))
-                confirmed_arc_keys.append(np.unique(v * sub.n + u))
-        off += ids.size
-    omega = np.asarray(state.omega).copy()
-    omega[:, q0] &= survived_all
-    edge_active = state.edge_active
-    if annotate:
-        if not constraint.complete:
-            raise ValueError("annotate requires a complete walk")
-        omega = confirmed & np.asarray(state.omega)
-        # exact edge set (paper: the output G* contains only edges of matches)
-        keys = (
-            np.unique(np.concatenate(confirmed_arc_keys))
-            if confirmed_arc_keys
-            else np.zeros(0, np.int64)
-        )
-        arc_keys = np.asarray(dg.src).astype(np.int64) * sub.n + np.asarray(dg.dst)
-        pos = np.searchsorted(keys, arc_keys)
-        pos = np.minimum(pos, max(keys.shape[0] - 1, 0))
-        exact = (keys.shape[0] > 0) & (keys[pos] == arc_keys) if keys.shape[0] else np.zeros(arc_keys.shape[0], bool)
-        edge_active = state.edge_active & jnp.asarray(exact)
+            arc_keys = (obs.to_host(dg.src, "src").astype(np.int64) * sub.n
+                        + obs.to_host(dg.dst, "dst"))
+            pos = np.searchsorted(keys, arc_keys)
+            pos = np.minimum(pos, max(keys.shape[0] - 1, 0))
+            exact = (keys.shape[0] > 0) & (keys[pos] == arc_keys) if keys.shape[0] else np.zeros(arc_keys.shape[0], bool)
+            edge_active = state.edge_active & jnp.asarray(exact)
     return PruneState(omega=jnp.asarray(omega), edge_active=edge_active)
