@@ -30,6 +30,17 @@ more (see `verify_constraint`). Three tunable routes execute a wave:
 `unpacked` boolean planes (scan-based hops), `packed` per-hop bitset_spmm
 launches, and the `fused` multi-hop bitset_wave kernel (pack/unpack once per
 wave, frontier resident across hops).
+
+Where a constraint's waves run as XLA programs — the `unpacked` route, and
+the `fused` route when `bitset_wave` resolves to its oracle (its VMEM gate
+refuses the graph, or off-TPU) — every hop steps over each arc it is given.
+So once per constraint the active arcs and the vertices they touch are
+compacted into a graph of their own (`compact_active`, capacities padded to
+powers of two) and all the constraint's waves run on it; `edge_active` does
+not change inside the wave loop, and inactive arcs add nothing to an OR, so
+the survivors are the same. The whole graph is kept where the capacity
+would not be under m, and on the kernel routes (`packed`, and `fused` where
+the kernel runs), which walk the whole graph's blocked structure.
 """
 from __future__ import annotations
 
@@ -281,6 +292,102 @@ def check_walk_constraint(
     return _wave_survivors(frontier, source_ids, safe_src, is_cyclic), total_msgs
 
 
+# Compacted wave graphs: arcs and vertices are padded to powers of two, at
+# least this many, so that each capacity compiles once.
+COMPACT_MIN = 1024
+# (arc, vertex) capacities built so far, per whole-graph shape (n, m)
+_compact_buckets: Dict[Tuple[int, int], list] = {}
+
+
+def _pow2(x: int) -> int:
+    return 1 << max(int(x) - 1, 0).bit_length()
+
+
+def compact_bucket(n: int, m: int, arcs: int, verts: int):
+    """The (arc, vertex) capacity a constraint's waves run at, or None to
+    run them on the whole graph. A capacity holds the active arcs, and the
+    vertices they touch plus one sink. The smallest capacity already built
+    for graphs of this shape that holds them is taken, so the set of wave
+    programs a graph needs stops growing once its largest active subgraphs
+    have run; else the next powers of two, when the arcs' is under m."""
+    built = _compact_buckets.setdefault((n, m), [])
+    fits = [b for b in built if b[0] >= arcs and b[1] > verts]
+    if fits:
+        return min(fits)
+    b = (_pow2(max(arcs, COMPACT_MIN)), _pow2(max(verts + 1, COMPACT_MIN)))
+    if b[0] >= m:
+        return None
+    built.append(b)
+    return b
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _active_extent(src, dst, edge_active, n: int):
+    """(touched bool[n]: the vertices an active arc starts or ends at,
+    int32[2]: the active arcs and the touched vertices)."""
+    ea = edge_active.astype(jnp.int32)
+    touched = ((segment_ops.segment_max(ea, dst, n) > 0)
+               | (segment_ops.segment_max(ea, src, n, sorted=False) > 0))
+    return touched, jnp.stack([jnp.sum(ea), jnp.sum(touched, dtype=jnp.int32)])
+
+
+@functools.partial(jax.jit, static_argnames=("m_c", "n_c"))
+def compact_active(dg: DeviceGraph, state: PruneState, touched, m_c: int, n_c: int):
+    """The active subgraph with its touched vertices renumbered from 0 in
+    their old order: (DeviceGraph with n_c vertices and m_c arcs, its
+    PruneState, int32[n] compact id of each vertex, -1 where no active arc
+    touches it).
+
+    The active arcs keep their dst-sorted order and the renumbering keeps
+    vertex order, so the compact dst stays sorted. Pad arcs are inactive
+    and end at the sink, the last compact id; pad vertices have no
+    candidacy. Inactive arcs add nothing to an OR, so a wave over the
+    compact graph reaches the same vertices as over the whole graph."""
+    (arc,) = jnp.nonzero(state.edge_active, size=m_c, fill_value=0)
+    live = jnp.arange(m_c) < jnp.sum(state.edge_active, dtype=jnp.int32)
+    (vid,) = jnp.nonzero(touched, size=n_c, fill_value=0)
+    real = jnp.arange(n_c) < jnp.sum(touched, dtype=jnp.int32)
+    to_c = jnp.cumsum(touched, dtype=jnp.int32) - 1
+    sink = n_c - 1
+    g = DeviceGraph(
+        n=n_c,
+        src=jnp.where(live, jnp.take(to_c, jnp.take(dg.src, arc)), sink),
+        dst=jnp.where(live, jnp.take(to_c, jnp.take(dg.dst, arc)), sink),
+        labels=jnp.where(real, jnp.take(dg.labels, vid), -1),
+    )
+    st = PruneState(omega=jnp.take(state.omega, vid, axis=0) & real[:, None],
+                    edge_active=live)
+    return g, st, jnp.where(touched, to_c, -1)
+
+
+@jax.jit
+def _compact_ids(to_c, source_ids):
+    """Wave source ids in compact ids; pads and sources no active arc
+    touches become -1 (a token there cannot move, so it never survives)."""
+    n = to_c.shape[0]
+    return jnp.where(source_ids >= 0,
+                     jnp.take(to_c, jnp.clip(source_ids, 0, n - 1)), -1)
+
+
+def _waves_on_xla(route, n, wave, hops, dg, edge_active, blocked, force_pallas):
+    """True where the constraint's waves run as XLA programs (the boolean
+    planes, or the fused route when `bitset_wave` resolves to its oracle),
+    whose hops step over every arc they are given; the kernels walk the
+    whole graph's blocked structure."""
+    from repro.kernels import ops as kops  # noqa: F401  (registers bitset_wave)
+    from repro.kernels import registry
+
+    if route == registry.ROUTE_UNPACKED:
+        return True
+    if route != registry.ROUTE_FUSED:
+        return False
+    vals = jax.ShapeDtypeStruct((n, wave // 32), jnp.uint32)
+    cand = jax.ShapeDtypeStruct((hops, n), jnp.uint32)
+    return registry.resolve_mode(
+        "bitset_wave", vals, dg.src, dg.dst, n, edge_active, cand, blocked,
+        force_pallas=force_pallas) == registry.MODE_REF
+
+
 @functools.partial(jax.jit, static_argnames=("is_cyclic",))
 def walk_frontiers_and_edges(
     dg: DeviceGraph,
@@ -372,9 +479,12 @@ def verify_constraint(
     plane, and the head-column eliminations (Alg. 5 line 8 — the heads are
     distinct template vertices across a constraint's walks) are applied on
     device at the end. The wave loop's host round-trips per constraint: one
-    head-candidacy read to size it, plus one message-count readback under
-    `count_messages` — never a per-wave `survived` transfer; these are what
-    `stats["nlcc_host_syncs"]` counts. Always sound (a
+    head-candidacy read to size it (which also brings the active subgraph's
+    arc and vertex counts where the waves may run on it), plus one
+    message-count readback under `count_messages` — never a per-wave
+    `survived` transfer; these are what `stats["nlcc_host_syncs"]` counts.
+    The `nlcc.wave_loop` span counts the arcs the hops stepped over
+    (`wave_arcs`) against m for the same hops (`graph_arcs`). Always sound (a
     token only survives by certifying a full walk, so no true match is ever
     pruned). For cycle rotations it is also exactly as strong as the old
     sequential per-rotation pass: a token completing rotation j through a
@@ -392,7 +502,8 @@ def verify_constraint(
     waves onto the `fused` multi-hop wave engine (`check_walk_constraint_fused`
     — one bitset_wave dispatch per wave, pack/unpack once) or the per-hop
     `packed` bitset_spmm route; the boolean-plane scan is the unpacked
-    fallback.
+    fallback. The XLA-program waves run on the compacted active subgraph
+    (module docstring, `compact_bucket`).
 
     edge_prune=True (requires template) additionally eliminates arcs that lie
     on NO completing walk for the template arcs this constraint covers — a
@@ -420,50 +531,74 @@ def verify_constraint(
         _registry.ROUTE_UNPACKED: "nlcc_plane_waves",
     }[route]
     omega = state.omega
-    n = omega.shape[0]
+    n, m = omega.shape[0], dg.m
     heads = [w[0] for w in walks]
-    # the wave loop's ONE host sync per constraint: the head-candidacy
-    # columns size it (everything downstream stays on device)
-    head_cols = obs.to_host(omega[:, jnp.asarray(heads, jnp.int32)], "nlcc.heads")
-    host_syncs = 1
-    with obs.span("nlcc.sources", kind="host"):
-        walk_sources = [np.flatnonzero(head_cols[:, wi]) for wi in range(len(walks))]
-    keep = jnp.zeros((len(walks), n), dtype=bool)
-    total_msgs = jnp.asarray(0)
-    n_waves = 0
-    for wi, walk in enumerate(walks):
-        cand = jnp.stack([omega[:, q] for q in walk], axis=0)  # bool[L+1, n]
-        if walk_sources[wi].size == 0:
-            continue
-        for ids_padded, n_real in wave_batches(walk_sources[wi], wave):
-            with obs.span("nlcc.wave"):
-                ids_dev = jnp.asarray(ids_padded, jnp.int32)
-                wave_state = PruneState(omega=omega, edge_active=state.edge_active)
-                if route == _registry.ROUTE_FUSED:
-                    survived = check_walk_constraint_fused(
-                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                        blocked, force_pallas=force_pallas,
-                    )
-                elif route == _registry.ROUTE_PACKED:
-                    survived = check_walk_constraint_packed(
-                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                        blocked, force_pallas=force_pallas,
-                    )
-                else:
-                    survived, n_msgs = check_walk_constraint(
-                        dg, wave_state, cand, walk[0] == walk[-1], ids_dev,
-                        count_messages=count_messages,
-                    )
-                    total_msgs = total_msgs + n_msgs
-                # pads clip to vertex 0 with survived=False — max() cannot unset
-                keep = keep.at[wi, jnp.clip(ids_dev, 0, n - 1)].max(survived)
-            n_waves += 1
-            if stats is not None:
-                stats["nlcc_tokens"] = stats.get("nlcc_tokens", 0) + n_real
-                stats[wave_stat] = stats.get(wave_stat, 0) + 1
-    # remove head candidacy from failing sources (Alg. 5 line 8), on device
-    for wi, q0 in enumerate(heads):
-        omega = omega.at[:, q0].set(omega[:, q0] & keep[wi])
+    hops = len(walks[0]) - 1
+    compactable = _waves_on_xla(route, n, wave, hops, dg, state.edge_active,
+                                blocked, force_pallas)
+    with obs.span("nlcc.wave_loop"):
+        head_idx = jnp.asarray(heads, jnp.int32)
+        # the wave loop's ONE host sync per constraint: the head-candidacy
+        # columns size it, with the active subgraph's extent where the waves
+        # may run on it (everything downstream stays on device)
+        if compactable:
+            touched, extent = _active_extent(dg.src, dg.dst, state.edge_active, n)
+            head_cols, extent = obs.to_host((omega[:, head_idx], extent), "nlcc.heads")
+        else:
+            head_cols = obs.to_host(omega[:, head_idx], "nlcc.heads")
+        host_syncs = 1
+        with obs.span("nlcc.sources", kind="host"):
+            walk_sources = [np.flatnonzero(head_cols[:, wi]) for wi in range(len(walks))]
+        bucket = None
+        if compactable and any(s.size for s in walk_sources):
+            bucket = compact_bucket(n, m, int(extent[0]), int(extent[1]))
+        if bucket is not None:
+            # every wave of the constraint runs on the active subgraph
+            wave_dg, wave_state, to_c = compact_active(
+                dg, state, touched, m_c=bucket[0], n_c=bucket[1])
+        else:
+            wave_dg, wave_state, to_c = dg, state, None
+        keep = jnp.zeros((len(walks), n), dtype=bool)
+        total_msgs = jnp.asarray(0)
+        n_waves = 0
+        for wi, walk in enumerate(walks):
+            if walk_sources[wi].size == 0:
+                continue
+            # bool[L+1, n_w], n_w the wave graph's vertex count
+            cand = jnp.stack([wave_state.omega[:, q] for q in walk], axis=0)
+            for ids_padded, n_real in wave_batches(walk_sources[wi], wave):
+                with obs.span("nlcc.wave"):
+                    ids_dev = jnp.asarray(ids_padded, jnp.int32)
+                    ids_w = ids_dev if to_c is None else _compact_ids(to_c, ids_dev)
+                    if route == _registry.ROUTE_FUSED:
+                        survived = check_walk_constraint_fused(
+                            wave_dg, wave_state, cand, walk[0] == walk[-1], ids_w,
+                            None if to_c is not None else blocked,
+                            force_pallas=force_pallas,
+                        )
+                    elif route == _registry.ROUTE_PACKED:
+                        survived = check_walk_constraint_packed(
+                            wave_dg, wave_state, cand, walk[0] == walk[-1], ids_w,
+                            blocked, force_pallas=force_pallas,
+                        )
+                    else:
+                        survived, n_msgs = check_walk_constraint(
+                            wave_dg, wave_state, cand, walk[0] == walk[-1], ids_w,
+                            count_messages=count_messages,
+                        )
+                        total_msgs = total_msgs + n_msgs
+                    # survivors land at the sources' own ids; pads clip to
+                    # vertex 0 with survived=False — max() cannot unset
+                    keep = keep.at[wi, jnp.clip(ids_dev, 0, n - 1)].max(survived)
+                n_waves += 1
+                if stats is not None:
+                    stats["nlcc_tokens"] = stats.get("nlcc_tokens", 0) + n_real
+                    stats[wave_stat] = stats.get(wave_stat, 0) + 1
+        obs.count("wave_arcs", (m if bucket is None else bucket[0]) * hops * n_waves)
+        obs.count("graph_arcs", m * hops * n_waves)
+        # remove head candidacy from failing sources (Alg. 5 line 8), on device
+        for wi, q0 in enumerate(heads):
+            omega = omega.at[:, q0].set(omega[:, q0] & keep[wi])
     if stats is not None:
         if count_messages:
             stats["nlcc_messages"] = stats.get("nlcc_messages", 0) + int(

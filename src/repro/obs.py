@@ -104,17 +104,24 @@ def count(key: str, n=1) -> None:
         c[key] = c.get(key, 0) + n
 
 
-def to_host(x, what: str) -> np.ndarray:
-    """`np.asarray(x)` under a `host.readback` span. An array whose host copy
-    JAX already holds moves nothing and counts nothing."""
-    if not isinstance(x, jax.Array):
-        return np.asarray(x)
-    with span("host.readback", what=what):
-        moved = getattr(x, "_npy_value", None) is None
-        out = np.asarray(x)
-        if moved:
-            count("readback_bytes", int(out.nbytes))
-    return out
+def to_host(x, what: str):
+    """`np.asarray(x)` under a `host.readback` span. A tuple of arrays moves
+    in the same one read and comes back as a tuple of numpy arrays. An array
+    whose host copy JAX already holds moves nothing and counts nothing."""
+    xs = x if isinstance(x, tuple) else (x,)
+    if not any(isinstance(a, jax.Array) for a in xs):
+        out = tuple(np.asarray(a) for a in xs)
+    else:
+        with span("host.readback", what=what):
+            moved = [isinstance(a, jax.Array) and getattr(a, "_npy_value", None) is None
+                     for a in xs]
+            for a, mv in zip(xs, moved):
+                if mv:
+                    a.copy_to_host_async()
+            out = tuple(np.asarray(a) for a in xs)
+            if any(moved):
+                count("readback_bytes", sum(int(o.nbytes) for o, mv in zip(out, moved) if mv))
+    return out if isinstance(x, tuple) else out[0]
 
 
 def spans() -> List[Span]:

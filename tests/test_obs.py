@@ -95,6 +95,20 @@ def test_to_host_counts_bytes_of_device_arrays_only():
     assert "readback_bytes" not in q.counters
 
 
+def test_to_host_moves_a_tuple_in_one_read():
+    with obs.span("q"):
+        a, b, c = obs.to_host((jnp.ones((4, 2), bool), jnp.arange(3, dtype=jnp.int32),
+                               np.arange(5)), "pair")
+        d, = obs.to_host((np.zeros(2),), "numpy")
+    np.testing.assert_array_equal(a, np.ones((4, 2), bool))
+    np.testing.assert_array_equal(b, np.arange(3))
+    np.testing.assert_array_equal(c, np.arange(5))
+    assert isinstance(d, np.ndarray)
+    reads = [s for s in obs.spans() if s.name == "host.readback"]
+    assert [s.attrs["what"] for s in reads] == ["pair"]
+    assert reads[0].counters == {"readback_bytes": 8 + 12}
+
+
 def test_a_forced_retrace_lands_in_trace_s():
     f = jax.jit(lambda x: jnp.sin(x) * 3 + 1)
     f(jnp.ones(3)).block_until_ready()
